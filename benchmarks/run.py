@@ -68,6 +68,10 @@ def main(argv=None) -> None:
                          "benchmark rows ('' disables)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     from benchmarks.adaptive import ALL_ADAPTIVE
     from benchmarks.figures import ALL_FIGURES
     from benchmarks.kernels import ALL_KERNELS

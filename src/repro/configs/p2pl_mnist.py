@@ -229,6 +229,7 @@ def directed_k8(
 
 def sharded_k8(
     *,
+    num_peers: int = 8,
     schedule: str = "static",
     protocol: str = "gossip",
     algorithm: str = "p2pl_affinity",
@@ -252,13 +253,16 @@ def sharded_k8(
 
         XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
             python -m repro.launch.train --experiment sharded_k8 --peer-axis pod
+
+    ``num_peers`` shrinks the ring (first K of the same class pairs) to fit
+    a host with fewer devices, e.g. K=4 on one four-chip TPU host.
     """
-    peer_classes = tuple(((2 * k) % 10, (2 * k + 1) % 10) for k in range(8))
+    peer_classes = tuple(((2 * k) % 10, (2 * k + 1) % 10) for k in range(num_peers))
     return PaperExperiment(
-        name=f"sharded_k8_{schedule}_{protocol}_{algorithm}_T{local_steps}",
+        name=f"sharded_k{num_peers}_{schedule}_{protocol}_{algorithm}_T{local_steps}",
         p2p=P2PConfig(
             algorithm=algorithm,
-            num_peers=8,
+            num_peers=num_peers,
             local_steps=local_steps,
             consensus_steps=1,
             lr=0.01,

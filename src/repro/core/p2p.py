@@ -54,6 +54,7 @@ all four {vmap, pod} x {python, scan} driver cells.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Any, Callable, NamedTuple
 
@@ -868,24 +869,9 @@ def run_round(
 
 
 def _shard_map_fn():
-    """Version-compat shard_map: jax.shard_map (>= 0.6) or the experimental
-    module it graduated from, with replication checking disabled either way
-    (the runtime's replicated outputs — round_idx, losses — are replicated by
-    construction; the check's rewrite rules don't cover every jax version)."""
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-    def wrap(f, *, mesh, in_specs, out_specs):
-        for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        raise RuntimeError("no compatible shard_map signature found")
-
-    return wrap
+    """``jax.shard_map`` with the varying-manual-axes check off: the runtime's
+    replicated outputs (round_idx, losses) are replicated by construction."""
+    return functools.partial(jax.shard_map, check_vma=False)
 
 
 def consensus_phase_sharded(

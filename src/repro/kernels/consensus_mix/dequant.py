@@ -24,10 +24,11 @@ term stays exact on the true ``x``.  The no-neighbor guard cannot read the
 folded beta (scale = 0 would corrupt it), so the RAW beta sum rides in as a
 separate flag.
 
-Layout matches ``consensus_mix.py``: (rows, 128) lanes, the grid tiles rows,
-one (D, BR, 128) int8 BlockSpec streams all payloads per tile.  Note the
-TPU int8 tile floor is (32, 128) vs fp32's (8, 128); the block-rows picker in
-``dequant_mix_flat`` prefers multiples of 32 accordingly.  The dense oracle
+Layout matches ``consensus_mix.py``: (rows, 128) lanes, the grid runs over
+peers and tiles rows, one (D, BR, 128) int8 BlockSpec streams a peer's
+payloads per tile.  Note the TPU int8 tile floor is (32, 128) vs fp32's
+(8, 128); ``dequant_mix_rows`` pads the lane rows to blocks that are
+multiples of 32 accordingly.  The dense oracle
 is ``ref.dequant_mix_ref`` (advance-then-mix, f32): the kernel must stay
 allclose to it in every cell.
 """
@@ -39,12 +40,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.consensus_mix.consensus_mix import LANE, DEFAULT_BLOCK_ROWS
-from repro.kernels.consensus_mix.ops import (
-    _pad_to_lanes,
-    flatten_pytree,
-    unflatten_pytree,
+from repro.kernels.consensus_mix.consensus_mix import (
+    DEFAULT_BLOCK_ROWS,
+    LANE,
+    lane_layout,
+    smem_spec,
+    sublane_multiple,
+    to_lanes,
 )
+from repro.kernels.consensus_mix.ops import flatten_pytree, unflatten_pytree
 
 PyTree = object
 
@@ -52,145 +56,120 @@ PyTree = object
 def _kernel(x_ref, self_est_ref, est_ref, q_ref, w_self_ref, w_nbr_ref,
             w_eff_ref, beta_ref, beta_eff_ref, has_nbrs_ref, inv_t_ref,
             mixed_ref, d_ref):
+    k = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)  # (BR, 128)
-    self_est = self_est_ref[...].astype(jnp.float32)  # (BR, 128)
-    est = est_ref[...].astype(jnp.float32)  # (D, BR, 128)
-    q = q_ref[...].astype(jnp.float32)  # (D, BR, 128) int8, cast in-register
-    w_self = w_self_ref[0]
-    w_nbr = w_nbr_ref[...]  # (D,)
-    w_eff = w_eff_ref[...]  # (D,) = w_nbr * scale — the advance folded in
-    beta = beta_ref[...]  # (D,)
-    beta_eff = beta_eff_ref[...]  # (D,) = beta * scale
-    inv_t = inv_t_ref[0]
-
-    mixed = (
-        w_self * x
-        + jnp.einsum("d,drl->rl", w_nbr, est)
-        + jnp.einsum("d,drl->rl", w_eff, q)
-    )
-    nbr_avg = (
-        jnp.einsum("d,drl->rl", beta, est)
-        + jnp.einsum("d,drl->rl", beta_eff, q)
-    )
+    # a weighted sum over the static D; the (K, D) weights are SMEM scalars,
+    # w_eff = w_nbr * scale and beta_eff = beta * scale fold the advance in
+    mixed = w_self_ref[k] * x
+    nbr_avg = jnp.zeros_like(x)
+    for j in range(q_ref.shape[0]):
+        est = est_ref[j].astype(jnp.float32)
+        q = q_ref[j].astype(jnp.float32)  # int8, cast in-register
+        mixed = mixed + w_nbr_ref[k, j] * est + w_eff_ref[k, j] * q
+        nbr_avg = nbr_avg + beta_ref[k, j] * est + beta_eff_ref[k, j] * q
     mixed_ref[...] = mixed.astype(mixed_ref.dtype)
     # the guard flag is the RAW beta sum (beta_eff would read 0 whenever a
     # sender's payload scale is 0, e.g. an all-zero difference)
+    self_est = self_est_ref[...].astype(jnp.float32)
     d = jnp.where(
-        has_nbrs_ref[0] > 0.0, (nbr_avg - self_est) * inv_t, jnp.zeros_like(x)
+        has_nbrs_ref[k] > 0.0, (nbr_avg - self_est) * inv_t_ref[0], jnp.zeros_like(x)
     )
     d_ref[...] = d.astype(d_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def dequant_mix_2d(
-    x: jax.Array,  # (R, 128) f32 — this peer's own TRUE lanes
-    self_est: jax.Array,  # (R, 128) f32 — this peer's own public estimate
-    nbrs_est: jax.Array,  # (D, R, 128) f32 — neighbor public estimates
-    nbrs_q: jax.Array,  # (D, R, 128) int8 — neighbor difference payloads
-    w_self: jax.Array,  # scalar
-    w_nbr: jax.Array,  # (D,)
-    w_eff: jax.Array,  # (D,) w_nbr * scale
-    beta: jax.Array,  # (D,)
-    beta_eff: jax.Array,  # (D,) beta * scale
-    has_nbrs: jax.Array,  # scalar: raw sum(beta), the no-neighbor guard
+    x: jax.Array,  # (K, R, 128) f32 — each peer's own TRUE lanes
+    self_est: jax.Array,  # (K, R, 128) f32 — each peer's own public estimate
+    nbrs_est: jax.Array,  # (K, D, R, 128) f32 — neighbor public estimates
+    nbrs_q: jax.Array,  # (K, D, R, 128) int8 — neighbor difference payloads
+    w_self: jax.Array,  # (K,)
+    w_nbr: jax.Array,  # (K, D)
+    w_eff: jax.Array,  # (K, D) w_nbr * scale
+    beta: jax.Array,  # (K, D)
+    beta_eff: jax.Array,  # (K, D) beta * scale
+    has_nbrs: jax.Array,  # (K,) raw sum(beta), the no-neighbor guard
     inv_t: jax.Array,  # scalar: 1 / local_steps
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
+    """Every peer's fused dequantize-and-mix in one pallas_call, grid
+    (K, row blocks)."""
     from repro.kernels import lowering
 
     interpret = lowering.resolve_interpret(interpret)
-    r, lane = x.shape
-    d = nbrs_q.shape[0]
-    assert lane == LANE and nbrs_q.shape[1:] == (r, LANE)
-    assert nbrs_est.shape == (d, r, LANE) and self_est.shape == (r, LANE)
+    k, r, lane = x.shape
+    d = nbrs_q.shape[1]
+    assert lane == LANE and nbrs_q.shape == (k, d, r, LANE)
+    assert nbrs_est.shape == (k, d, r, LANE) and self_est.shape == (k, r, LANE)
     assert nbrs_q.dtype == jnp.int8
     br = min(block_rows, r)
     assert r % br == 0, f"rows {r} not divisible by block {br}"
 
-    grid = (r // br,)
+    tile = pl.BlockSpec((None, br, LANE), lambda p, i: (p, i, 0))
+    slab = pl.BlockSpec((None, d, br, LANE), lambda p, i: (p, 0, i, 0))
     out_shape = (
-        jax.ShapeDtypeStruct((r, LANE), x.dtype),
-        jax.ShapeDtypeStruct((r, LANE), x.dtype),
+        jax.ShapeDtypeStruct((k, r, LANE), x.dtype),
+        jax.ShapeDtypeStruct((k, r, LANE), x.dtype),
     )
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((d, br, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((d, br, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((br, LANE), lambda i: (i, 0)),
-        ],
+        grid=(k, r // br),
+        in_specs=[tile, tile, slab, slab] + [smem_spec()] * 7,
+        out_specs=[tile, tile],
         out_shape=out_shape,
         interpret=interpret,
     )(
-        x, self_est, nbrs_est, nbrs_q, w_self.reshape(1), w_nbr, w_eff,
-        beta, beta_eff, has_nbrs.reshape(1), inv_t.reshape(1),
+        x, self_est, nbrs_est, nbrs_q, f32(w_self), f32(w_nbr), f32(w_eff),
+        f32(beta), f32(beta_eff), f32(has_nbrs), f32(inv_t).reshape(1),
     )
 
 
-def dequant_mix_flat(
-    x: jax.Array,  # (N,) f32 — own TRUE parameters
-    self_est: jax.Array,  # (N,) f32 — own public estimate
-    nbrs_est: jax.Array,  # (D, N) f32 — neighbor public estimates
-    nbrs_q: jax.Array,  # (D, N) int8 — difference payloads
-    nbr_scale: jax.Array,  # (D,) fp32 payload scales
-    w_self: jax.Array,
-    w_nbr: jax.Array,  # (D,)
-    beta: jax.Array,  # (D,)
+def dequant_mix_rows(
+    x: jax.Array,  # (K, N) f32 — each peer's own TRUE parameters
+    self_est: jax.Array,  # (K, N) f32 — each peer's own public estimate
+    nbrs_est: jax.Array,  # (K, D, N) f32 — gathered neighbor estimates
+    nbrs_q: jax.Array,  # (K, D, N) int8 — gathered difference payloads
+    nbr_scale: jax.Array,  # (K, D) fp32 payload scales
+    w_self: jax.Array,  # (K,)
+    w_nbr: jax.Array,  # (K, D)
+    beta: jax.Array,  # (K, D)
     local_steps: int,
     *,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused dequantize-and-mix on flattened vectors; one peer's row.
+    """Fused dequantize-and-mix of K flattened rows: (mixed, d), both (K, N).
 
     Must stay allclose to ``ref.dequant_mix_ref`` (which materializes the
     advanced fp32 neighbors ``est + q * scale``); the kernel instead folds
     ``nbr_scale`` into the weights and accumulates straight from int8.
     """
-    x2, n = _pad_to_lanes(x)
-    se2, _ = _pad_to_lanes(self_est)
-    ne2, _ = _pad_to_lanes(nbrs_est)
-    nb2, _ = _pad_to_lanes(nbrs_q)
-    rows = x2.shape[0]
-    # pick a block that divides rows; multiples of 32 first (int8 tile floor)
-    br = rows
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if rows % cand == 0:
-            br = cand
-            break
+    k, n = x.shape
+    # x, self_est and the two outputs, plus D estimate and D int8 payload
+    # rows, per lane row; the int8 payload sets the (32, 128) tile floor
+    rows, br = lane_layout(
+        n,
+        multiple=sublane_multiple(x.dtype, nbrs_q.dtype),
+        row_bytes=4 * 4 + nbrs_q.shape[1] * (4 + 1),
+    )
     w_nbr = jnp.asarray(w_nbr, jnp.float32)
     beta = jnp.asarray(beta, jnp.float32)
     scale = jnp.asarray(nbr_scale, jnp.float32)
     mixed, d = dequant_mix_2d(
-        x2,
-        se2,
-        ne2,
-        nb2,
-        jnp.asarray(w_self, jnp.float32),
+        *(to_lanes(a, rows) for a in (x, self_est, nbrs_est, nbrs_q)),
+        w_self,
         w_nbr,
         w_nbr * scale,
         beta,
         beta * scale,
-        jnp.sum(beta),
-        jnp.asarray(1.0 / local_steps, jnp.float32),
+        jnp.sum(beta, axis=1),
+        1.0 / local_steps,
         block_rows=br,
         interpret=interpret,
     )
-    return mixed.reshape(-1)[:n], d.reshape(-1)[:n]
+    return mixed.reshape(k, -1)[:, :n], d.reshape(k, -1)[:, :n]
 
 
 def quantize_int8(flat: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -233,19 +212,10 @@ def dequant_consensus_mix_stacked(
     advance; the caller advances its carried copy with ``est + q * scale``.
     """
     flat, _ = flatten_pytree(stacked)  # (K, N) f32
-    k = flat.shape[0]
-
-    def per_peer(xk, my, sw, idx, wn, bt):
-        nbrs_q = q[idx]  # (D, N) int8 gather — stays compressed in HBM
-        nbrs_e = est[idx]  # (D, N) f32 estimates
-        sc = scale[idx]  # (D,)
-        return dequant_mix_flat(
-            xk, est[my], nbrs_e, nbrs_q, sc, sw, wn, bt, local_steps,
-            interpret=interpret,
-        )
-
-    mixed, d = jax.vmap(per_peer)(
-        flat, jnp.arange(k), self_w, nbr_idx, nbr_w, beta
+    # (K, D, N) gathers: the int8 payloads stay compressed in HBM
+    mixed, d = dequant_mix_rows(
+        flat, est, est[nbr_idx], q[nbr_idx], scale[nbr_idx], self_w, nbr_w, beta,
+        local_steps, interpret=interpret,
     )
     return unflatten_pytree(stacked, mixed), unflatten_pytree(stacked, d)
 

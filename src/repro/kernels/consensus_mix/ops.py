@@ -1,6 +1,6 @@
 """jit'd public API for the fused consensus kernel.
 
-``consensus_mix_flat``    — operates on flattened (N,) parameter vectors.
+``consensus_mix_rows``    — operates on K flattened (N,) parameter rows.
 ``consensus_mix_stacked`` — drop-in accelerated form of one gossip step over a
 stacked (K, ...) parameter pytree with a sparse (padded-neighbor) mixing
 matrix; used by the P2P runtime when ``use_kernel=True``.
@@ -39,43 +39,38 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import consensus as consensus_lib
-from repro.kernels.consensus_mix.consensus_mix import LANE, consensus_mix_2d
+from repro.kernels.consensus_mix.consensus_mix import (
+    consensus_mix_2d,
+    lane_layout,
+    sublane_multiple,
+    to_lanes,
+)
 
 PyTree = object
 
 
-def _pad_to_lanes(x: jax.Array) -> tuple[jax.Array, int]:
-    n = x.shape[-1]
-    rows = -(-n // LANE)
-    pad = rows * LANE - n
-    if pad:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-    return x.reshape(x.shape[:-1] + (rows, LANE)), n
-
-
-def consensus_mix_flat(
-    x: jax.Array,  # (N,)
-    nbrs: jax.Array,  # (D, N)
-    w_self: jax.Array,
-    w_nbr: jax.Array,  # (D,)
-    beta: jax.Array,  # (D,)
+def consensus_mix_rows(
+    x: jax.Array,  # (K, N)
+    nbrs: jax.Array,  # (K, D, N) each peer's gathered neighbors
+    w_self: jax.Array,  # (K,)
+    w_nbr: jax.Array,  # (K, D)
+    beta: jax.Array,  # (K, D)
     local_steps: int,
     *,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
+    """Fused mix + affinity d of K flattened rows: (mixed, d), both (K, N)."""
     # interpret=None resolves inside consensus_mix_2d (repro.kernels.lowering)
-    x2, n = _pad_to_lanes(x)
-    nb2, _ = _pad_to_lanes(nbrs)
-    rows = x2.shape[0]
-    # pick a block that divides rows
-    br = rows
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if rows % cand == 0:
-            br = cand
-            break
+    k, n = x.shape
+    # x and the two outputs, plus the D neighbor rows, per lane row
+    rows, br = lane_layout(
+        n,
+        multiple=sublane_multiple(x.dtype, nbrs.dtype),
+        row_bytes=3 * x.dtype.itemsize + nbrs.shape[1] * nbrs.dtype.itemsize,
+    )
     mixed, d = consensus_mix_2d(
-        x2,
-        nb2,
+        to_lanes(x, rows),
+        to_lanes(nbrs, rows),
         jnp.asarray(w_self, jnp.float32),
         jnp.asarray(w_nbr, jnp.float32),
         jnp.asarray(beta, jnp.float32),
@@ -83,7 +78,7 @@ def consensus_mix_flat(
         block_rows=br,
         interpret=interpret,
     )
-    return mixed.reshape(-1)[:n], d.reshape(-1)[:n]
+    return mixed.reshape(k, -1)[:, :n], d.reshape(k, -1)[:, :n]
 
 
 def flatten_pytree(tree: PyTree) -> tuple[jax.Array, list]:
@@ -120,13 +115,10 @@ def consensus_mix_stacked(
     tensor is read once.  Returns (mixed_params, d_bias).
     """
     flat, _ = flatten_pytree(stacked)  # (K, N)
-    k = flat.shape[0]
-
-    def per_peer(xk, sw, idx, wn, bt):
-        nbrs = flat[idx]  # (D, N) gather — stays in HBM, tiles stream to VMEM
-        return consensus_mix_flat(xk, nbrs, sw, wn, bt, local_steps, interpret=interpret)
-
-    mixed, d = jax.vmap(per_peer)(flat, self_w, nbr_idx, nbr_w, beta)
+    # (K, D, N) gather — stays in HBM, tiles stream to VMEM
+    mixed, d = consensus_mix_rows(
+        flat, flat[nbr_idx], self_w, nbr_w, beta, local_steps, interpret=interpret
+    )
     return unflatten_pytree(stacked, mixed), unflatten_pytree(stacked, d)
 
 
@@ -162,11 +154,9 @@ def consensus_mix_push_sum_stacked(
     self_w_y = self_w * massf
     nbr_w_y = nbr_w * massf[nbr_idx]
 
-    def per_peer(xk, sw, idx, wn, bt):
-        nbrs = aug[idx]  # (D, N+1) gather — stays in HBM, tiles stream to VMEM
-        return consensus_mix_flat(xk, nbrs, sw, wn, bt, local_steps, interpret=interpret)
-
-    mixed, d = jax.vmap(per_peer)(aug, self_w_y, nbr_idx, nbr_w_y, beta)
+    mixed, d = consensus_mix_rows(
+        aug, aug[nbr_idx], self_w_y, nbr_w_y, beta, local_steps, interpret=interpret
+    )
     new_mass = mixed[:, -1]
     debiased = mixed[:, :-1] / new_mass[:, None]
     return (

@@ -33,7 +33,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.consensus_mix.consensus_mix import LANE, DEFAULT_BLOCK_ROWS
+from repro.kernels.consensus_mix.consensus_mix import (
+    DEFAULT_BLOCK_ROWS,
+    LANE,
+    lane_layout,
+    sublane_multiple,
+    to_lanes,
+)
 
 
 def _segment_kernel(
@@ -42,6 +48,7 @@ def _segment_kernel(
     idx_ref,  # SMEM (K, D)
     nbr_w_ref,  # SMEM (K, D)
     beta_ref,  # SMEM (K, D)
+    has_nbrs_ref,  # SMEM (K,) 1.0 where the peer's beta row is not all zero
     inv_t_ref,  # SMEM (1,)
     x_self_ref,  # VMEM (1, BR, LANE) — peer k's own tile
     x_nbr_ref,  # VMEM (1, BR, LANE) — neighbor idx_ref[k, d]'s tile
@@ -70,9 +77,8 @@ def _segment_kernel(
         # all-zero beta row = isolated peer this round: d stays 0 instead of
         # decaying the peer toward the origin (dense-path semantics)
         acc = d_ref[0].astype(jnp.float32)
-        has_nbrs = jnp.sum(beta_ref[k, :]) > 0.0
         out = jnp.where(
-            has_nbrs, (acc - x) * inv_t_ref[0], jnp.zeros_like(x)
+            has_nbrs_ref[k] > 0.0, (acc - x) * inv_t_ref[0], jnp.zeros_like(x)
         )
         d_ref[0] = out.astype(d_ref.dtype)
 
@@ -108,14 +114,14 @@ def segment_mix_2d(
 
     grid = (k, r // br, d)
     spec_self = pl.BlockSpec(
-        (1, br, LANE), lambda pk, pr, pd, sw, idx, nw, bt, it: (pk, pr, 0)
+        (1, br, LANE), lambda pk, pr, pd, sw, idx, nw, bt, hn, it: (pk, pr, 0)
     )
     spec_nbr = pl.BlockSpec(
         (1, br, LANE),
-        lambda pk, pr, pd, sw, idx, nw, bt, it: (idx[pk, pd], pr, 0),
+        lambda pk, pr, pd, sw, idx, nw, bt, hn, it: (idx[pk, pd], pr, 0),
     )
     spec_out = pl.BlockSpec(
-        (1, br, LANE), lambda pk, pr, pd, sw, idx, nw, bt, it: (pk, pr, 0)
+        (1, br, LANE), lambda pk, pr, pd, sw, idx, nw, bt, hn, it: (pk, pr, 0)
     )
     out_shape = (
         jax.ShapeDtypeStruct((k, r, LANE), x.dtype),
@@ -124,7 +130,7 @@ def segment_mix_2d(
     return pl.pallas_call(
         functools.partial(_segment_kernel, d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=grid,
             in_specs=[spec_self, spec_nbr],
             out_specs=[spec_out, spec_out],
@@ -136,27 +142,27 @@ def segment_mix_2d(
         nbr_idx.astype(jnp.int32),
         nbr_w.astype(jnp.float32),
         beta.astype(jnp.float32),
+        (jnp.sum(beta, axis=1) > 0.0).astype(jnp.float32),
         jnp.asarray(inv_t, jnp.float32).reshape(1),
         x,
         x,
     )
 
 
-def _pad_rows(flat: jax.Array) -> tuple[jax.Array, int]:
-    """(K, N) -> (K, R, LANE) lane tiling, padded with zeros; returns N."""
+def _segment_mix_flat(flat, self_w, nbr_idx, nbr_w, beta, local_steps, interpret):
+    """(K, N) -> mixed, d (K, N) through ``segment_mix_2d``: lane rows padded
+    to a whole number of blocks (multiples of the dtype's tile floor)."""
     k, n = flat.shape
-    rows = -(-n // LANE)
-    pad = rows * LANE - n
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    return flat.reshape(k, rows, LANE), n
-
-
-def _pick_block(rows: int) -> int:
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if rows % cand == 0:
-            return cand
-    return rows
+    # the peer's tile, one neighbor tile and the two outputs per lane row
+    rows, br = lane_layout(
+        n, multiple=sublane_multiple(flat.dtype), row_bytes=4 * flat.dtype.itemsize
+    )
+    mixed, d = segment_mix_2d(
+        to_lanes(flat, rows), self_w, nbr_idx, nbr_w, beta,
+        jnp.asarray(1.0 / local_steps, jnp.float32),
+        block_rows=br, interpret=interpret,
+    )
+    return mixed.reshape(k, -1)[:, :n], d.reshape(k, -1)[:, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("local_steps", "interpret"))
@@ -178,15 +184,9 @@ def segment_mix_stacked(
     from repro.kernels.consensus_mix import ops
 
     flat, _ = ops.flatten_pytree(stacked)  # (K, N)
-    x3, n = _pad_rows(flat)
-    mixed, d = segment_mix_2d(
-        x3, self_w, nbr_idx, nbr_w, beta,
-        jnp.asarray(1.0 / local_steps, jnp.float32),
-        block_rows=_pick_block(x3.shape[1]), interpret=interpret,
+    mixed, d = _segment_mix_flat(
+        flat, self_w, nbr_idx, nbr_w, beta, local_steps, interpret
     )
-    k = flat.shape[0]
-    mixed = mixed.reshape(k, -1)[:, :n]
-    d = d.reshape(k, -1)[:, :n]
     return ops.unflatten_pytree(stacked, mixed), ops.unflatten_pytree(stacked, d)
 
 
@@ -219,14 +219,9 @@ def segment_mix_push_sum_stacked(
     self_w_y = self_w * massf
     nbr_w_y = nbr_w * massf[nbr_idx]  # (K, D) — edge-list sized, not (K, K)
 
-    x3, n_aug = _pad_rows(aug)
-    mixed, d = segment_mix_2d(
-        x3, self_w_y, nbr_idx, nbr_w_y, beta,
-        jnp.asarray(1.0 / local_steps, jnp.float32),
-        block_rows=_pick_block(x3.shape[1]), interpret=interpret,
+    mixed, d = _segment_mix_flat(
+        aug, self_w_y, nbr_idx, nbr_w_y, beta, local_steps, interpret
     )
-    mixed = mixed.reshape(k, -1)[:, :n_aug]
-    d = d.reshape(k, -1)[:, :n_aug]
     new_mass = mixed[:, -1]
     debiased = mixed[:, :-1] / new_mass[:, None]
     return (

@@ -39,12 +39,7 @@ ICI_BW = 50e9  # B/s per link
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults to Auto
-    # semantics anyway, so only pass axis_types where it exists.
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -76,8 +71,7 @@ def make_peer_mesh(num_peers: int, *, axis_name: str = "pod"):
             f"--xla_force_host_platform_device_count={num_peers} set before "
             "the first jax import (see repro/launch/mesh.py)."
         )
-    # jax.sharding.Mesh (not jax.make_mesh): stable across supported jax
-    # versions and accepts an explicit device subset.
+    # jax.sharding.Mesh (not jax.make_mesh): it takes an explicit device subset
     return jax.sharding.Mesh(np.asarray(devices[:num_peers]), (axis_name,))
 
 

@@ -143,10 +143,6 @@ def build_report(
     }
     wire = float(colls["wire_bytes"])
     extra = dict(extra or {})
-    # jax <= 0.4.x returns cost_analysis() as a one-element list of dicts;
-    # newer jax returns the dict directly
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     extra["xla_cost_analysis"] = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

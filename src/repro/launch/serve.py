@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
+from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
 from repro.models import build_model
 
@@ -186,6 +187,18 @@ def serve_batch(
     return result
 
 
+def fleet_inputs(model, num_peers: int, batch: int, prompt_len: int, seed: int = 0):
+    """The (K, ...) parameter stack and (K, B, prompt_len) prompts that
+    ``serve_fleet`` serves for ``seed``."""
+    stacked_params = jax.vmap(model.init)(
+        jax.random.split(jax.random.PRNGKey(seed), num_peers)
+    )
+    prompts = jax.vmap(lambda k: model.make_batch(k, batch, prompt_len))(
+        jax.random.split(jax.random.PRNGKey(seed + 1), num_peers)
+    )
+    return stacked_params, prompts
+
+
 def serve_fleet(
     arch: str = "smollm-135m",
     *,
@@ -214,12 +227,7 @@ def serve_fleet(
     if use_reduced:
         cfg = reduced(cfg)
     model = build_model(cfg)
-    stacked_params = jax.vmap(model.init)(
-        jax.random.split(jax.random.PRNGKey(seed), num_peers)
-    )
-    prompts = jax.vmap(lambda k: model.make_batch(k, batch, prompt_len))(
-        jax.random.split(jax.random.PRNGKey(seed + 1), num_peers)
-    )
+    stacked_params, prompts = fleet_inputs(model, num_peers, batch, prompt_len, seed)
     caches = stack_request_caches(
         model.init_cache(batch, prompt_len + gen_tokens), num_peers
     )
@@ -302,4 +310,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -48,6 +48,7 @@ from repro.core import p2p
 from repro.core import protocols as protocols_lib
 from repro.core import task as task_lib
 from repro.data import partition, synthetic
+from repro.launch import compile_cache
 from repro.models import build_model
 
 
@@ -341,6 +342,8 @@ def run_p2p_lm(
 
 
 def main(argv=None):
+    """The training CLI; returns ``(RoundLog, final P2PState)`` of a paper
+    experiment (``None`` for ``p2p_lm``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--experiment", default="noniid_affinity",
                     choices=["iid_k100", "noniid_local_dsgd", "noniid_affinity",
@@ -635,18 +638,21 @@ def main(argv=None):
                 f"--xla_force_host_platform_device_count={need} set before "
                 "the first jax import."
             )
-    log = run_paper_experiment(
+    log, state = run_paper_experiment(
         exp, rounds=args.rounds, verbose=True, peer_axis=args.peer_axis,
         driver=args.driver, eval_every=args.eval_every,
         peers_per_device=args.peers_per_device, mix_mode=args.mix_mode,
+        return_state=True,
     )
-    print(f"done in {time.time()-t0:.1f}s")
+    print(f"done in {time.time()-t0:.1f}s (includes compile)")
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
             f.write(log.to_json())
         print("wrote", args.out)
+    return log, state
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

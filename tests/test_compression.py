@@ -433,18 +433,19 @@ def test_cli_rejects_bad_combinations(argv, msg, capsys):
 @pytest.mark.parametrize("n", [64, 257, 1000])
 @pytest.mark.parametrize("d", [1, 3, 5])
 def test_dequant_mix_matches_oracle(n, d, rng):
-    x = jnp.asarray(rng.normal(size=n), jnp.float32)
-    self_est = jnp.asarray(rng.normal(size=n), jnp.float32)
-    nbrs_est = jnp.asarray(rng.normal(size=(d, n)), jnp.float32)
-    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(d, n)), jnp.int8)
-    scale = jnp.asarray(rng.uniform(0.0, 0.1, size=d), jnp.float32)
-    w_nbr = jnp.asarray(rng.dirichlet(np.ones(d + 1))[:d], jnp.float32)
-    w_self = jnp.asarray(1.0 - w_nbr.sum())
-    beta = jnp.asarray(rng.dirichlet(np.ones(d)), jnp.float32)
-    got_m, got_d = dequant.dequant_mix_flat(
+    k = 3  # peers ride the kernel's grid axis; the oracle runs per peer
+    x = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    self_est = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    nbrs_est = jnp.asarray(rng.normal(size=(k, d, n)), jnp.float32)
+    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(k, d, n)), jnp.int8)
+    scale = jnp.asarray(rng.uniform(0.0, 0.1, size=(k, d)), jnp.float32)
+    w_nbr = jnp.asarray(rng.dirichlet(np.ones(d + 1), size=k)[:, :d], jnp.float32)
+    w_self = 1.0 - w_nbr.sum(axis=1)
+    beta = jnp.asarray(rng.dirichlet(np.ones(d), size=k), jnp.float32)
+    got_m, got_d = dequant.dequant_mix_rows(
         x, self_est, nbrs_est, nbrs_q, scale, w_self, w_nbr, beta, 10
     )
-    want_m, want_d = cm_ref.dequant_mix_ref(
+    want_m, want_d = jax.vmap(cm_ref.dequant_mix_ref, in_axes=(0,) * 8 + (None,))(
         x, self_est, nbrs_est, nbrs_q, scale, w_self, w_nbr, beta, 10
     )
     np.testing.assert_allclose(np.asarray(got_m), np.asarray(want_m),
@@ -456,36 +457,36 @@ def test_dequant_mix_matches_oracle(n, d, rng):
 def test_dequant_mix_zero_beta_keeps_zero_d(rng):
     """The no-neighbor guard reads the RAW beta sum: d is exactly zero even
     when payload scales are nonzero."""
-    n, d = 256, 3
-    x = jnp.asarray(rng.normal(size=n), jnp.float32)
-    self_est = jnp.asarray(rng.normal(size=n), jnp.float32)
-    nbrs_est = jnp.asarray(rng.normal(size=(d, n)), jnp.float32)
-    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(d, n)), jnp.int8)
-    scale = jnp.full((d,), 0.05, jnp.float32)
-    _, got_d = dequant.dequant_mix_flat(
-        x, self_est, nbrs_est, nbrs_q, scale, jnp.asarray(1.0),
-        jnp.zeros((d,), jnp.float32), jnp.zeros((d,), jnp.float32), 10
+    k, n, d = 2, 256, 3
+    x = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    self_est = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    nbrs_est = jnp.asarray(rng.normal(size=(k, d, n)), jnp.float32)
+    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(k, d, n)), jnp.int8)
+    scale = jnp.full((k, d), 0.05, jnp.float32)
+    _, got_d = dequant.dequant_mix_rows(
+        x, self_est, nbrs_est, nbrs_q, scale, jnp.ones((k,), jnp.float32),
+        jnp.zeros((k, d), jnp.float32), jnp.zeros((k, d), jnp.float32), 10
     )
-    assert np.array_equal(np.asarray(got_d), np.zeros(n, np.float32))
+    assert np.array_equal(np.asarray(got_d), np.zeros((k, n), np.float32))
 
 
 def test_dequant_mix_zero_scale_ignores_payload(rng):
     """scale = 0 (an all-zero difference) folds the payload away entirely:
     the mix runs on the bare estimates."""
-    n, d = 128, 2
-    x = jnp.asarray(rng.normal(size=n), jnp.float32)
-    self_est = jnp.asarray(rng.normal(size=n), jnp.float32)
-    nbrs_est = jnp.asarray(rng.normal(size=(d, n)), jnp.float32)
-    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(d, n)), jnp.int8)
-    w_nbr = jnp.full((d,), 0.3, jnp.float32)
-    beta = jnp.full((d,), 0.5, jnp.float32)
-    got_m, got_d = dequant.dequant_mix_flat(
-        x, self_est, nbrs_est, nbrs_q, jnp.zeros((d,), jnp.float32),
-        jnp.asarray(0.4), w_nbr, beta, 10
+    k, n, d = 2, 128, 2
+    x = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    self_est = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    nbrs_est = jnp.asarray(rng.normal(size=(k, d, n)), jnp.float32)
+    nbrs_q = jnp.asarray(rng.integers(-127, 128, size=(k, d, n)), jnp.int8)
+    w_self = jnp.full((k,), 0.4, jnp.float32)
+    w_nbr = jnp.full((k, d), 0.3, jnp.float32)
+    beta = jnp.full((k, d), 0.5, jnp.float32)
+    zero_scale = jnp.zeros((k, d), jnp.float32)
+    got_m, got_d = dequant.dequant_mix_rows(
+        x, self_est, nbrs_est, nbrs_q, zero_scale, w_self, w_nbr, beta, 10
     )
-    want_m, want_d = cm_ref.dequant_mix_ref(
-        x, self_est, nbrs_est, jnp.zeros_like(nbrs_q),
-        jnp.zeros((d,), jnp.float32), jnp.asarray(0.4), w_nbr, beta, 10
+    want_m, want_d = jax.vmap(cm_ref.dequant_mix_ref, in_axes=(0,) * 8 + (None,))(
+        x, self_est, nbrs_est, jnp.zeros_like(nbrs_q), zero_scale, w_self, w_nbr, beta, 10
     )
     np.testing.assert_allclose(np.asarray(got_m), np.asarray(want_m),
                                atol=5e-5, rtol=1e-4)

@@ -37,7 +37,9 @@ def _run(arch, shape_name, shape, axes, multi):
     p = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
+        # the child runs on the CPU: it must never contend for a chip that
+        # this process (or another worker) holds
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin", "JAX_PLATFORMS": "cpu"},
     )
     assert p.returncode == 0, p.stderr[-3000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])

@@ -25,13 +25,16 @@ TOL = {jnp.float32: dict(atol=5e-5, rtol=1e-4), jnp.bfloat16: dict(atol=5e-2, rt
 @pytest.mark.parametrize("d", [1, 3, 5])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_consensus_mix_sweep(n, d, dtype, rng):
-    x = jnp.asarray(rng.normal(size=n), dtype)
-    nbrs = jnp.asarray(rng.normal(size=(d, n)), dtype)
-    w_nbr = jnp.asarray(rng.dirichlet(np.ones(d + 1))[:d], jnp.float32)
-    w_self = jnp.asarray(1.0 - w_nbr.sum())
-    beta = jnp.asarray(rng.dirichlet(np.ones(d)), jnp.float32)
-    got_m, got_d = cm_ops.consensus_mix_flat(x, nbrs, w_self, w_nbr, beta, 10)
-    want_m, want_d = cm_ref.consensus_mix_ref(x, nbrs, w_self, w_nbr, beta, 10)
+    k = 3  # peers ride the kernel's grid axis; the oracle runs per peer
+    x = jnp.asarray(rng.normal(size=(k, n)), dtype)
+    nbrs = jnp.asarray(rng.normal(size=(k, d, n)), dtype)
+    w_nbr = jnp.asarray(rng.dirichlet(np.ones(d + 1), size=k)[:, :d], jnp.float32)
+    w_self = 1.0 - w_nbr.sum(axis=1)
+    beta = jnp.asarray(rng.dirichlet(np.ones(d), size=k), jnp.float32)
+    got_m, got_d = cm_ops.consensus_mix_rows(x, nbrs, w_self, w_nbr, beta, 10)
+    want_m, want_d = jax.vmap(cm_ref.consensus_mix_ref, in_axes=(0, 0, 0, 0, 0, None))(
+        x, nbrs, w_self, w_nbr, beta, 10
+    )
     np.testing.assert_allclose(
         np.asarray(got_m, np.float32), np.asarray(want_m, np.float32), **TOL[dtype]
     )
@@ -42,12 +45,12 @@ def test_consensus_mix_sweep(n, d, dtype, rng):
 
 def test_consensus_mix_preserves_constant(rng):
     """Row-stochastic mixing of identical params is the identity."""
-    n = 512
-    x = jnp.ones((n,), jnp.float32) * 3.25
-    nbrs = jnp.broadcast_to(x, (4, n))
-    w_nbr = jnp.full((4,), 0.2, jnp.float32)
-    got_m, got_d = cm_ops.consensus_mix_flat(x, nbrs, jnp.asarray(0.2), w_nbr,
-                                             jnp.full((4,), 0.25, jnp.float32), 5)
+    k, n = 2, 512
+    x = jnp.ones((k, n), jnp.float32) * 3.25
+    nbrs = jnp.broadcast_to(x[:, None], (k, 4, n))
+    w_nbr = jnp.full((k, 4), 0.2, jnp.float32)
+    got_m, got_d = cm_ops.consensus_mix_rows(x, nbrs, jnp.full((k,), 0.2), w_nbr,
+                                             jnp.full((k, 4), 0.25, jnp.float32), 5)
     np.testing.assert_allclose(np.asarray(got_m), 3.25, rtol=1e-6)
     np.testing.assert_allclose(np.asarray(got_d), 0.0, atol=1e-7)
 
