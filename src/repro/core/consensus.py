@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
+
 PyTree = Any
 
 
@@ -327,25 +329,33 @@ def max_norm_sync(stacked: PyTree) -> PyTree:
 
 
 def consensus_error(stacked: PyTree) -> jax.Array:
-    """Model drift metric: mean_k ||w_k - w_bar||_2 over all leaves (f32)."""
-    leaves = jax.tree.leaves(stacked)
-    k = leaves[0].shape[0]
-    sq = jnp.zeros((k,), jnp.float32)
-    for x in leaves:
-        xf = x.astype(jnp.float32).reshape(k, -1)
-        mean = jnp.mean(xf, axis=0, keepdims=True)
-        sq = sq + jnp.sum(jnp.square(xf - mean), axis=1)
-    return jnp.mean(jnp.sqrt(sq))
+    """Model drift metric: mean_k ||w_k - w_bar||_2 over all leaves (f32).
+
+    Run eagerly; the span ``consensus.consensus_error`` times the dispatch
+    of its ops."""
+    with telemetry.span("consensus.consensus_error"):
+        leaves = jax.tree.leaves(stacked)
+        k = leaves[0].shape[0]
+        sq = jnp.zeros((k,), jnp.float32)
+        for x in leaves:
+            xf = x.astype(jnp.float32).reshape(k, -1)
+            mean = jnp.mean(xf, axis=0, keepdims=True)
+            sq = sq + jnp.sum(jnp.square(xf - mean), axis=1)
+        return jnp.mean(jnp.sqrt(sq))
 
 
 def pairwise_drift(stacked: PyTree) -> jax.Array:
-    """Max over peer pairs of ||w_i - w_j||_2 — the paper's drift/divergence."""
-    leaves = jax.tree.leaves(stacked)
-    k = leaves[0].shape[0]
-    sq = jnp.zeros((k, k), jnp.float32)
-    for x in leaves:
-        xf = x.astype(jnp.float32).reshape(k, -1)
-        # ||x_i - x_j||^2 = ||x_i||^2 + ||x_j||^2 - 2 x_i . x_j
-        n2 = jnp.sum(xf * xf, axis=1)
-        sq = sq + n2[:, None] + n2[None, :] - 2.0 * (xf @ xf.T)
-    return jnp.sqrt(jnp.maximum(sq, 0.0)).max()
+    """Max over peer pairs of ||w_i - w_j||_2 — the paper's drift/divergence.
+
+    Run eagerly; the span ``consensus.pairwise_drift`` times the dispatch
+    of its ops."""
+    with telemetry.span("consensus.pairwise_drift"):
+        leaves = jax.tree.leaves(stacked)
+        k = leaves[0].shape[0]
+        sq = jnp.zeros((k, k), jnp.float32)
+        for x in leaves:
+            xf = x.astype(jnp.float32).reshape(k, -1)
+            # ||x_i - x_j||^2 = ||x_i||^2 + ||x_j||^2 - 2 x_i . x_j
+            n2 = jnp.sum(xf * xf, axis=1)
+            sq = sq + n2[:, None] + n2[None, :] - 2.0 * (xf @ xf.T)
+        return jnp.sqrt(jnp.maximum(sq, 0.0)).max()

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import compression as compression_lib
+from repro import telemetry
 from repro.core import consensus as consensus_lib
 from repro.core import features as features_lib
 from repro.core import graph as graph_lib
@@ -505,6 +506,7 @@ def init_state(
 # ---------------------------------------------------------------------------
 
 
+@telemetry.scoped("repro.local")
 def _local_phase_stats(
     state: P2PState,
     loss_fn: LossFn,
@@ -627,6 +629,7 @@ def local_phase(
 # ---------------------------------------------------------------------------
 
 
+@telemetry.scoped("repro.consensus")
 def consensus_phase(
     state: P2PState,
     cfg: P2PConfig,
@@ -874,6 +877,7 @@ def _shard_map_fn():
     return functools.partial(jax.shard_map, check_vma=False)
 
 
+@telemetry.scoped("repro.consensus")
 def consensus_phase_sharded(
     state: P2PState,
     cfg: P2PConfig,
@@ -1221,6 +1225,7 @@ MIX_MODES = ("auto", "bridge", "segment")
 _BRIDGE_MAX_PEERS = 64  # "auto" uses the bit-parity bridge mix up to here
 
 
+@telemetry.scoped("repro.consensus")
 def consensus_phase_hier(
     state: P2PState,
     cfg: P2PConfig,
@@ -1748,13 +1753,16 @@ def make_scan_driver(
     (``peer_axis="pod"``) runtime, chunk axis outside the ``shard_map``.
     The chunk length C is not baked in: it is read from the batch shapes, and
     each distinct C compiles once (drive with ONE chunk size per run to keep
-    the one-compile property).
+    the one-compile property).  The jitted program is ``jit_drive``,
+    registered with ``repro.telemetry`` as ``drive``.
     """
+    donate_argnums = (0,) if donate else ()
     step = _make_round_step(
         loss_fn, cfg, data_sizes, mesh=mesh, axis_name=axis_name,
         peers_per_device=peers_per_device, mix_mode=mix_mode,
     )
 
+    @telemetry.program("drive", donate_argnums=donate_argnums)
     def drive(state: P2PState, batches: PyTree):
         def body(carry, batches_r):
             st, _ = carry
@@ -1766,7 +1774,7 @@ def make_scan_driver(
         (final, last_local), losses = jax.lax.scan(body, (state, state), batches)
         return last_local, final, losses
 
-    return jax.jit(drive, donate_argnums=(0,) if donate else ())
+    return jax.jit(drive, donate_argnums=donate_argnums)
 
 
 # ---------------------------------------------------------------------------
@@ -1833,6 +1841,7 @@ def evaluate_stacked(
     return jax.vmap(acc)(params)
 
 
+@telemetry.scoped("repro.eval")
 def stratified_accuracy(
     apply_fn: Callable[[PyTree, jax.Array], jax.Array],
     params: PyTree,

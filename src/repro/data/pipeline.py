@@ -11,6 +11,8 @@ from typing import Iterator
 
 import numpy as np
 
+from repro import telemetry
+
 
 class PeerBatcher:
     """Cyclic per-peer mini-batch sampler over heterogeneous local datasets."""
@@ -48,17 +50,21 @@ class PeerBatcher:
         return sel
 
     def round_batches(self, local_steps: int) -> tuple[np.ndarray, np.ndarray]:
-        """Batches for one round: (x (T,K,B,F), y (T,K,B))."""
-        xs, ys = [], []
-        for _t in range(local_steps):
-            bx, by = [], []
-            for k in range(self.num_peers):
-                sel = self._next_indices(k)
-                bx.append(self.parts[k][0][sel])
-                by.append(self.parts[k][1][sel])
-            xs.append(np.stack(bx))
-            ys.append(np.stack(by))
-        return np.stack(xs), np.stack(ys)
+        """Batches for one round: (x (T,K,B,F), y (T,K,B)); the span
+        ``data.round_batches`` and the counter ``data.samples``."""
+        with telemetry.span("data.round_batches"):
+            xs, ys = [], []
+            for _t in range(local_steps):
+                bx, by = [], []
+                for k in range(self.num_peers):
+                    sel = self._next_indices(k)
+                    bx.append(self.parts[k][0][sel])
+                    by.append(self.parts[k][1][sel])
+                xs.append(np.stack(bx))
+                ys.append(np.stack(by))
+            out = np.stack(xs), np.stack(ys)
+        telemetry.count("data.samples", local_steps * self.num_peers * self.b)
+        return out
 
     def rounds(self, num_rounds: int, local_steps: int) -> Iterator[tuple[np.ndarray, np.ndarray]]:
         for _ in range(num_rounds):

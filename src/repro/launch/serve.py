@@ -23,12 +23,13 @@ CLI:  python -m repro.launch.serve --arch smollm-135m --batch 4 --gen 8
 from __future__ import annotations
 
 import argparse
-import time
+import sys
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.configs import get_config, reduced
 from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
@@ -37,6 +38,7 @@ from repro.models import build_model
 PyTree = Any
 
 
+@telemetry.scoped("repro.route")
 def route_params(stacked_params: PyTree, peer_ids: jax.Array) -> PyTree:
     """Gather each request group's parameter rows: (K, ...) -> (G, ...).
 
@@ -55,10 +57,12 @@ def make_fleet_generate_fn(model, gen_tokens: int) -> Callable:
     Request group g decodes under peer ``peer_ids[g]``'s weights: a traced
     gather routes the parameter rows, then the fused prefill+scan generate
     (``steps.make_generate_fn``) is vmapped over the group axis.  Jit with
-    ``donate_argnums=(2,)`` to reuse the cache buffers in place.
+    ``donate_argnums=(2,)`` to reuse the cache buffers in place; the
+    program is ``jit_fleet``, registered with ``repro.telemetry`` as ``fleet``.
     """
     generate = steps_lib.make_generate_fn(model, gen_tokens)
 
+    @telemetry.program("fleet", donate_argnums=(2,))
     def fleet(stacked_params, prompts, caches, peer_ids):
         routed = route_params(stacked_params, peer_ids)
         return jax.vmap(generate)(routed, prompts, caches)
@@ -101,10 +105,11 @@ def serve_batch(
 ) -> dict:
     """Single-model serving: prefill, then greedy-decode ``gen_tokens - 1``.
 
-    Timing follows benchmarks/timing.py's discipline: jax dispatches
-    asynchronously, so inputs are blocked on before the start timestamp and
-    outputs before the stop timestamp — a bare ``time.time()`` around a jit
-    call measures enqueue time, not execution time (and the reported times
+    The times are the spans ``serve.prefill`` and ``serve.decode``
+    (``repro.telemetry``), on benchmarks/timing.py's discipline: jax
+    dispatches asynchronously, so inputs are blocked on before a span opens
+    and outputs before it closes — a bare clock around a jit call measures
+    enqueue time, not execution time (and the reported times
     here still include compile, since each jit runs once; steady-state
     numbers live in benchmarks/serving.py).
 
@@ -133,10 +138,10 @@ def serve_batch(
     prefill = jax.jit(steps_lib.make_prefill_step(model))
 
     jax.block_until_ready((params, prompt, cache))
-    t0 = time.perf_counter()
-    tok, cache = prefill(params, prompt, cache)
-    jax.block_until_ready((tok, cache))
-    prefill_s = time.perf_counter() - t0
+    with telemetry.span("serve.prefill") as prefill_span:
+        tok, cache = prefill(params, prompt, cache)
+        jax.block_until_ready((tok, cache))
+    prefill_s = prefill_span.seconds
 
     decode_steps = gen_tokens - 1
     if decode_steps == 0:
@@ -150,22 +155,20 @@ def serve_batch(
                 steps_lib.make_decode_scan(model, decode_steps),
                 donate_argnums=(1,),
             )
-            t0 = time.perf_counter()
-            gen, cache = decode(params, cache, tok, pos)
-            jax.block_until_ready((gen, cache))
-            decode_s = time.perf_counter() - t0
+            with telemetry.span("serve.decode") as decode_span:
+                gen, cache = decode(params, cache, tok, pos)
+                jax.block_until_ready((gen, cache))
         else:
             serve = jax.jit(steps_lib.make_serve_step(model))
             first, toks = tok, []
-            t0 = time.perf_counter()
-            for _ in range(decode_steps):
-                tok, pos, cache = serve(params, cache, tok, pos)
-                toks.append(tok)
-            jax.block_until_ready((toks, cache))
-            decode_s = time.perf_counter() - t0
+            with telemetry.span("serve.decode") as decode_span:
+                for _ in range(decode_steps):
+                    tok, pos, cache = serve(params, cache, tok, pos)
+                    toks.append(tok)
+                jax.block_until_ready((toks, cache))
             gen, tok = jnp.stack(toks, axis=1), first
         out = jnp.concatenate([tok[:, None], gen], axis=1)
-        decode_s_per_token = decode_s / decode_steps
+        decode_s_per_token = decode_span.seconds / decode_steps
 
     result = {
         "tokens": out,  # (B, gen_tokens)
@@ -245,10 +248,10 @@ def serve_fleet(
         peer_ids = specs_lib.shard_peer_tree(peer_ids, mesh)
 
     jax.block_until_ready((stacked_params, prompts, caches, peer_ids))
-    t0 = time.perf_counter()
-    tokens, caches = fleet(stacked_params, prompts, caches, peer_ids)
-    jax.block_until_ready(tokens)
-    serve_s = time.perf_counter() - t0
+    with telemetry.span("serve.fleet") as fleet_span:
+        tokens, caches = fleet(stacked_params, prompts, caches, peer_ids)
+        jax.block_until_ready(tokens)
+    serve_s = fleet_span.seconds
 
     total_tokens = int(tokens.shape[0] * tokens.shape[1] * tokens.shape[2])
     result = {
@@ -297,16 +300,17 @@ def main(argv=None):
             peer_axis=args.peer_axis,
             verbose=True,
         )
-        return
-    serve_batch(
-        args.arch,
-        batch=args.batch,
-        prompt_len=args.prompt_len,
-        gen_tokens=args.gen,
-        use_reduced=not args.full,
-        verbose=True,
-        decode_impl=args.decode_impl,
-    )
+    else:
+        serve_batch(
+            args.arch,
+            batch=args.batch,
+            prompt_len=args.prompt_len,
+            gen_tokens=args.gen,
+            use_reduced=not args.full,
+            verbose=True,
+            decode_impl=args.decode_impl,
+        )
+    print(telemetry.summary(), file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -213,7 +213,8 @@ def make_generate_fn(model: Model, gen_tokens: int) -> Callable:
     come from ``make_decode_scan``.  ``gen_tokens == 1`` skips the scan
     STRUCTURALLY (prefill only — the explicit empty decode).  Returning the
     final cache lets callers jit with ``donate_argnums`` on the cache slot:
-    the input buffers are reused in place for the output cache.
+    the input buffers are reused in place for the output cache.  Prefill runs
+    under the named scope ``repro.prefill``, decode under ``repro.decode``.
     """
     if gen_tokens < 1:
         raise ValueError(f"need gen_tokens >= 1, got {gen_tokens}")
@@ -221,11 +222,13 @@ def make_generate_fn(model: Model, gen_tokens: int) -> Callable:
     decode = make_decode_scan(model, gen_tokens - 1) if gen_tokens > 1 else None
 
     def generate(params, batch, cache):
-        tok, cache = prefill(params, batch, cache)
+        with jax.named_scope("repro.prefill"):
+            tok, cache = prefill(params, batch, cache)
         if decode is None:
             return tok[:, None], cache
-        pos = jnp.full(tok.shape, prompt_dec_len(batch), jnp.int32)
-        toks, cache = decode(params, cache, tok, pos)
-        return jnp.concatenate([tok[:, None], toks], axis=1), cache
+        with jax.named_scope("repro.decode"):
+            pos = jnp.full(tok.shape, prompt_dec_len(batch), jnp.int32)
+            toks, cache = decode(params, cache, tok, pos)
+            return jnp.concatenate([tok[:, None], toks], axis=1), cache
 
     return generate

@@ -20,7 +20,7 @@ import argparse
 import dataclasses
 import json
 import os
-import time
+import sys
 from typing import Optional
 
 import jax
@@ -40,6 +40,7 @@ from repro.configs.p2pl_mnist import (
     timevarying_k8,
 )
 from repro import compression as compression_lib
+from repro import telemetry
 from repro.core import consensus as consensus_lib
 from repro.core import features as features_lib
 from repro.core import graph as graph_lib
@@ -248,28 +249,32 @@ def run_paper_experiment(
         r = 0
         while r < rounds:
             n = min(eval_every, rounds - r)
-            bx, by = batcher.round_batches(cfg.local_steps * n)
-            # (n*T, K, ...) -> (n, T, K, ...): rounds-major chunk layout
-            bx = bx.reshape((n, cfg.local_steps) + bx.shape[1:])
-            by = by.reshape((n, cfg.local_steps) + by.shape[1:])
-            # the input state is DONATED to the scan: use only the returns
-            after_local, state, losses = drive_fn(
-                state, (jnp.asarray(bx), jnp.asarray(by))
-            )
+            with telemetry.span("train.batches"):
+                bx, by = batcher.round_batches(cfg.local_steps * n)
+                # (n*T, K, ...) -> (n, T, K, ...): rounds-major chunk layout
+                bx = bx.reshape((n, cfg.local_steps) + bx.shape[1:])
+                by = by.reshape((n, cfg.local_steps) + by.shape[1:])
+                feed = (jnp.asarray(bx), jnp.asarray(by))
+            with telemetry.span("train.dispatch"):
+                # the input state is DONATED to the scan: use only the returns
+                after_local, state, losses = drive_fn(state, feed)
             r += n
             # one eval (and at most one host transfer) per chunk, on the last
             # round's phase-boundary states; losses[-1] is that round's (T,)
-            record_eval(r - 1, after_local, state, losses[-1])
+            with telemetry.span("train.eval"):
+                record_eval(r - 1, after_local, state, losses[-1])
     else:
         for r in range(rounds):
-            bx, by = batcher.round_batches(cfg.local_steps)
-            after_local, after_cons, losses = round_fn(
-                state, (jnp.asarray(bx), jnp.asarray(by))
-            )
+            with telemetry.span("train.batches"):
+                bx, by = batcher.round_batches(cfg.local_steps)
+                feed = (jnp.asarray(bx), jnp.asarray(by))
+            with telemetry.span("train.dispatch"):
+                after_local, after_cons, losses = round_fn(state, feed)
             state = after_cons
             if (r + 1) % eval_every == 0 or r == rounds - 1:
                 # eval at period ends only: non-eval rounds transfer NOTHING
-                record_eval(r, after_local, after_cons, losses)
+                with telemetry.span("train.eval"):
+                    record_eval(r, after_local, after_cons, losses)
     if return_state:
         return log, state
     return log
@@ -475,12 +480,12 @@ def main(argv=None):
     if not 0.0 < args.topk_frac <= 1.0:
         ap.error(f"--topk-frac must be in (0, 1], got {args.topk_frac}")
 
-    t0 = time.time()
     if args.experiment == "p2p_lm":
         if args.peer_axis != "vmap":
             ap.error("p2p_lm runs the vmap runtime only (--peer-axis vmap)")
         out = run_p2p_lm(args.arch, rounds=args.rounds or 8, verbose=True)
         print(json.dumps(out))
+        print(telemetry.summary(), file=sys.stderr)
         return
     if args.experiment in ("timevarying_k2", "timevarying_k8"):
         builder = timevarying_k2 if args.experiment == "timevarying_k2" else timevarying_k8
@@ -644,7 +649,7 @@ def main(argv=None):
         peers_per_device=args.peers_per_device, mix_mode=args.mix_mode,
         return_state=True,
     )
-    print(f"done in {time.time()-t0:.1f}s (includes compile)")
+    print(telemetry.summary(), file=sys.stderr)
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:

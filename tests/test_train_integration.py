@@ -92,7 +92,7 @@ def test_cli_round_robin_and_protocol_flags(data, capsys, monkeypatch):
         "--round-robin-topologies", "complete,disconnected",
         "--protocol", "push_sum", "--rounds", "1",
     ])
-    assert "done in" in capsys.readouterr().out
+    assert "telemetry: spans" in capsys.readouterr().err
 
 
 def test_cli_adaptive_composes_with_scan_driver(data, capsys, monkeypatch):
@@ -112,7 +112,7 @@ def test_cli_adaptive_composes_with_scan_driver(data, capsys, monkeypatch):
         "--partner-rule", "eps_greedy", "--adaptive-eps", "0.3",
         "--adaptive-seed", "7", "--driver", "scan", "--rounds", "1",
     ])
-    assert "done in" in capsys.readouterr().out
+    assert "telemetry: spans" in capsys.readouterr().err
     assert seen["exp"].p2p.schedule == "adaptive"
     assert seen["exp"].p2p.partner_rule == "eps_greedy"
     assert seen["exp"].p2p.adaptive_eps == 0.3
