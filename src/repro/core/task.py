@@ -21,7 +21,7 @@ A task provides:
 ``make_peer_batches(parts, batch_size, *, seed) -> batcher``
     Batcher over the per-peer shards of ``data/partition.py``; its
     ``round_batches(T)`` returns a batch pytree whose leaves are (T, K, ...)
-    numpy arrays — step-major then peer, the ``local_phase`` layout.
+    device arrays — step-major then peer, the ``local_phase`` layout.
 ``prepare_eval(x) -> inputs``
     Maps raw evaluation images to the model's input format (identity for the
     MLP; pixel-stream tokenization for sequence models).

@@ -4,14 +4,29 @@ Produces per-round batches of shape (T, K, B, ...) — step-major, then peer —
 matching ``repro.core.p2p.local_phase``.  Each peer cycles through its own
 local dataset with per-peer reshuffling at epoch boundaries (mini-batch SGD
 as in the paper: B=10, one epoch = n_k/B iterations).
+
+The shards live on the device: the first ``round_batches`` call uploads all
+K shards once, as one (N, ...) array of inputs and one (N,) array of labels.
+Each call then draws its batches' global row indices on the host, one
+(T, K, B) int32 array, sends only that, and gathers the batches on the
+device in one jitted call.  The counter ``data.h2d_bytes`` holds what the
+pipeline sends host-to-device: the shards once, then the index arrays.
 """
 from __future__ import annotations
 
 from typing import Iterator
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
+
+
+@jax.jit
+def _gather(x: jax.Array, y: jax.Array, idx: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Rows ``idx`` (any shape) of the resident shards: x (*idx, ...), y (*idx)."""
+    return jnp.take(x, idx, axis=0, mode="clip"), jnp.take(y, idx, axis=0, mode="clip")
 
 
 class PeerBatcher:
@@ -28,45 +43,60 @@ class PeerBatcher:
         self.parts = parts
         self.b = batch_size
         self.reshuffle = reshuffle
+        self.sizes = [len(p[0]) for p in parts]
+        self.offsets = np.cumsum([0] + self.sizes[:-1])
         self.rngs = [np.random.default_rng(seed + 7 * k) for k in range(len(parts))]
-        self.orders = [rng.permutation(len(p[0])) for rng, p in zip(self.rngs, parts)]
+        self.orders = [rng.permutation(n) for rng, n in zip(self.rngs, self.sizes)]
         self.cursors = [0] * len(parts)
+        self._shards = None  # (x (N, ...), y (N,)) on the device, from the first call
 
     @property
     def num_peers(self) -> int:
         return len(self.parts)
 
-    def _next_indices(self, k: int) -> np.ndarray:
-        n = len(self.parts[k][0])
-        if n < self.b:
-            # sample with replacement when the local set is tiny
-            return self.rngs[k].integers(0, n, size=self.b)
-        if self.cursors[k] + self.b > n:
-            self.cursors[k] = 0
-            if self.reshuffle:
-                self.orders[k] = self.rngs[k].permutation(n)
-        sel = self.orders[k][self.cursors[k] : self.cursors[k] + self.b]
-        self.cursors[k] += self.b
-        return sel
+    def _next_indices(self, k: int, steps: int) -> np.ndarray:
+        """Peer k's next ``steps`` batches of local row indices, (steps, B),
+        drawn from the peer's own stream in the per-step order."""
+        n, b, rng = self.sizes[k], self.b, self.rngs[k]
+        if n < b:
+            # sample with replacement when the local set is tiny, one draw a step
+            return np.stack([rng.integers(0, n, size=b) for _ in range(steps)])
+        out = []
+        while steps:
+            if self.cursors[k] + b > n:
+                self.cursors[k] = 0
+                if self.reshuffle:
+                    self.orders[k] = rng.permutation(n)
+            c = self.cursors[k]
+            m = min(steps, (n - c) // b)
+            out.append(self.orders[k][c : c + m * b].reshape(m, b))
+            self.cursors[k] += m * b
+            steps -= m
+        return np.concatenate(out)
 
-    def round_batches(self, local_steps: int) -> tuple[np.ndarray, np.ndarray]:
-        """Batches for one round: (x (T,K,B,F), y (T,K,B)); the span
-        ``data.round_batches`` and the counter ``data.samples``."""
+    def _device_shards(self) -> tuple[jax.Array, jax.Array]:
+        if self._shards is None:
+            x = np.concatenate([p[0] for p in self.parts])
+            y = np.concatenate([p[1] for p in self.parts])
+            self._shards = jax.device_put((x, y))
+            telemetry.count("data.h2d_bytes", sum(a.nbytes for a in self._shards))
+        return self._shards
+
+    def round_batches(self, local_steps: int) -> tuple[jax.Array, jax.Array]:
+        """Batches for one round, as device arrays not waited on: (x (T,K,B,F),
+        y (T,K,B)); the span ``data.round_batches`` and the counters
+        ``data.samples`` and ``data.h2d_bytes``."""
         with telemetry.span("data.round_batches"):
-            xs, ys = [], []
-            for _t in range(local_steps):
-                bx, by = [], []
-                for k in range(self.num_peers):
-                    sel = self._next_indices(k)
-                    bx.append(self.parts[k][0][sel])
-                    by.append(self.parts[k][1][sel])
-                xs.append(np.stack(bx))
-                ys.append(np.stack(by))
-            out = np.stack(xs), np.stack(ys)
+            x, y = self._device_shards()
+            idx = np.empty((local_steps, self.num_peers, self.b), np.int32)
+            for k in range(self.num_peers):
+                idx[:, k] = self._next_indices(k, local_steps) + self.offsets[k]
+            out = _gather(x, y, idx)
         telemetry.count("data.samples", local_steps * self.num_peers * self.b)
+        telemetry.count("data.h2d_bytes", idx.nbytes)
         return out
 
-    def rounds(self, num_rounds: int, local_steps: int) -> Iterator[tuple[np.ndarray, np.ndarray]]:
+    def rounds(self, num_rounds: int, local_steps: int) -> Iterator[tuple[jax.Array, jax.Array]]:
         for _ in range(num_rounds):
             yield self.round_batches(local_steps)
 
@@ -106,9 +136,10 @@ class TokenSequenceBatcher:
     Tokenizes each peer's shard ONCE up front (``images_to_tokens``), then
     delegates sampling to an inner ``PeerBatcher`` — identical cursor /
     reshuffle / seed behavior, so sequence tasks see the same epoch structure
-    as the MLP.  ``round_batches(T)`` returns ``(tokens (T, K, B, L) int32,
-    labels (T, K, B) int32)`` — the same two-leaf tuple contract, so the
-    drivers' stacking and scan-chunk reshapes apply unchanged.
+    as the MLP, and the same device-resident shards and device gather.
+    ``round_batches(T)`` returns ``(tokens (T, K, B, L) int32, labels
+    (T, K, B) int32)`` as device arrays — the same two-leaf tuple contract, so
+    the drivers' stacking and scan-chunk reshapes apply unchanged.
     """
 
     def __init__(
@@ -133,7 +164,7 @@ class TokenSequenceBatcher:
     def num_peers(self) -> int:
         return self.inner.num_peers
 
-    def round_batches(self, local_steps: int) -> tuple[np.ndarray, np.ndarray]:
+    def round_batches(self, local_steps: int) -> tuple[jax.Array, jax.Array]:
         return self.inner.round_batches(local_steps)
 
     def rounds(self, num_rounds: int, local_steps: int):

@@ -330,6 +330,20 @@ def test_pipeline_counts_samples():
     assert [n for n, _, _, _ in telemetry.events()] == ["data.round_batches"] * 2
 
 
+def test_pipeline_sends_the_shards_once_then_only_indices():
+    parts = _parts()
+    shard_bytes = sum(x.nbytes + y.astype(np.int32).nbytes for x, y in parts)
+    index_bytes = T * K * 5 * 4
+    batcher = PeerBatcher(parts, 5, seed=0)
+    sent = []
+    for _ in range(3):
+        batcher.round_batches(T)
+        sent.append(telemetry.counters()["data.h2d_bytes"])
+    assert sent == [shard_bytes + index_bytes * i for i in (1, 2, 3)]
+    assert telemetry.counters()["data.samples"] == 3 * T * K * 5
+    assert [n for n, _, _, _ in telemetry.events()] == ["data.round_batches"] * 3
+
+
 def test_drift_and_error_record_spans():
     stacked = {"w": jnp.arange(12.0).reshape(3, 4)}
     float(consensus_lib.pairwise_drift(stacked))
