@@ -5,6 +5,7 @@ import dataclasses
 
 from repro.configs import (
     deepseek_v2_236b,
+    deepseek_v2_lite,
     internvl2_2b,
     minitron_8b,
     phi4_mini_3_8b,
@@ -20,6 +21,7 @@ from repro.configs.base import (
     AttentionConfig,
     ModelConfig,
     MoEConfig,
+    RopeScaling,
     ShapeConfig,
     SSMConfig,
 )
@@ -29,6 +31,7 @@ ARCHITECTURES = {
     "minitron-8b": minitron_8b.config,
     "seamless-m4t-medium": seamless_m4t_medium.config,
     "deepseek-v2-236b": deepseek_v2_236b.config,
+    "deepseek-v2-lite": deepseek_v2_lite.config,
     "phi4-mini-3.8b": phi4_mini_3_8b.config,
     "zamba2-2.7b": zamba2_2_7b.config,
     "qwen1.5-32b": qwen1_5_32b.config,
@@ -127,6 +130,7 @@ __all__ = [
     "ModelConfig",
     "MoEConfig",
     "NATIVE_LONG_CTX_FAMILIES",
+    "RopeScaling",
     "SSMConfig",
     "ShapeConfig",
     "for_shape",

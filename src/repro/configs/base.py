@@ -8,6 +8,24 @@ FAMILIES = ("dense", "moe", "rwkv6", "hybrid", "encdec", "vlm")
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of the rotary frequencies (arXiv:2309.00071), in
+    DeepSeek-V2's form: frequency indices below the correction range of
+    ``beta_fast`` rotations keep their frequency, those above the range of
+    ``beta_slow`` are divided by ``factor``, a linear ramp in between; cos and
+    sin are scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim),
+    and attention that states ``mscale_all_dim`` multiplies its softmax scale
+    by mscale(factor, mscale_all_dim) squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionConfig:
     num_heads: int
     num_kv_heads: int
@@ -15,6 +33,7 @@ class AttentionConfig:
     kind: str = "gqa"  # "gqa" | "mla"
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None  # None: plain rotary frequencies
     # MLA (DeepSeek-V2) fields
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
@@ -23,8 +42,6 @@ class AttentionConfig:
     v_head_dim: int = 0
     # long-context variant
     sliding_window: Optional[int] = None  # None = full causal
-    # decode-path optimization (MLA only): weight-absorbed latent attention
-    mla_absorb: bool = False
     # KV-cache storage: "model" dtype or "int8" (per-slot-per-head absmax
     # quantization; halves decode cache bytes, a §Perf serving feature)
     cache_quant: str = "model"
@@ -50,9 +67,20 @@ class MoEConfig:
     num_shared: int = 0
     first_dense_layers: int = 0  # leading layers use a dense MLP (DeepSeek-V2)
     dense_ff: int = 0  # d_ff of those dense layers
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training dispatch only; serving drops nothing
     router_aux_coef: float = 0.01
     router_groups: int = 1  # token groups for local routing (set to data-axis size at scale)
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0  # multiplies the routed experts' gates
+    # expert parallelism: this device holds experts [expert_offset,
+    # expert_offset + experts_held) of the num_experts the router scores;
+    # 0 holds them all
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,7 +161,7 @@ class ModelConfig:
         if self.moe is not None:
             m = self.moe
             expert = 3 * d * m.expert_ff
-            per_layer_mlp = m.num_experts * expert + m.num_shared * expert + d * m.num_experts
+            per_layer_mlp = m.held * expert + m.num_shared * expert + d * m.num_experts
             moe_layers = l - m.first_dense_layers
             total += moe_layers * (per_layer_attn + per_layer_mlp + 2 * d)
             total += m.first_dense_layers * (per_layer_attn + 3 * d * m.dense_ff + 2 * d)

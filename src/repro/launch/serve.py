@@ -40,7 +40,8 @@ PyTree = Any
 
 @telemetry.scoped("repro.route")
 def route_params(stacked_params: PyTree, peer_ids: jax.Array) -> PyTree:
-    """Gather each request group's parameter rows: (K, ...) -> (G, ...).
+    """Gather each request group's parameter rows: (K, ...) -> (G, ...), or
+    one model's (K, ...) -> (...) for a scalar ``peer_ids``.
 
     ``peer_ids`` (G,) int32 is a TRACED value — routing changes never
     recompile (``jnp.take`` with a traced index, not python indexing).
@@ -56,16 +57,26 @@ def make_fleet_generate_fn(model, gen_tokens: int) -> Callable:
 
     Request group g decodes under peer ``peer_ids[g]``'s weights: a traced
     gather routes the parameter rows, then the fused prefill+scan generate
-    (``steps.make_generate_fn``) is vmapped over the group axis.  Jit with
-    ``donate_argnums=(2,)`` to reuse the cache buffers in place; the
+    (``steps.make_generate_fn``) runs on each group: all at once, vmapped
+    over the group axis, or, for a model with an expert layer, one group
+    after another (``lax.map``), each routing only its own model's rows,
+    since the TPU's grouped product (``moe.apply``) takes no batch axis.
+    Jit with ``donate_argnums=(2,)`` to reuse the cache buffers in place; the
     program is ``jit_fleet``, registered with ``repro.telemetry`` as ``fleet``.
     """
     generate = steps_lib.make_generate_fn(model, gen_tokens)
 
     @telemetry.program("fleet", donate_argnums=(2,))
     def fleet(stacked_params, prompts, caches, peer_ids):
-        routed = route_params(stacked_params, peer_ids)
-        return jax.vmap(generate)(routed, prompts, caches)
+        if model.cfg.moe is None:
+            routed = route_params(stacked_params, peer_ids)
+            return jax.vmap(generate)(routed, prompts, caches)
+
+        def one_group(group):
+            prompt, cache, peer_id = group
+            return generate(route_params(stacked_params, peer_id), prompt, cache)
+
+        return jax.lax.map(one_group, (prompts, caches, peer_ids))
 
     return fleet
 

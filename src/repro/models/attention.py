@@ -7,8 +7,9 @@ position stored in each slot (-1 = empty).  Full-causal caches have
 ``long_500k`` decode O(window) in memory for attention archs.
 
 MLA caches the *latent* (c_kv, k_rope) — the paper-faithful DeepSeek-V2
-design; ``mla_absorb`` switches decode to the weight-absorbed form that never
-re-expands K/V over the cache length (a §Perf item).
+design.  One query position against a cache (decode) runs the weight-absorbed
+form, which never re-expands K/V over the cache length (arXiv:2405.04434
+§2.1.2); prefill and training run the expanded form.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.configs.base import AttentionConfig
 from repro.models import common
 from repro.sharding import logical
@@ -181,8 +183,8 @@ def gqa_apply(
     q = logical.shard(q, "batch", "seq", "heads", "head_dim")
     k = logical.shard(k, "batch", "seq", "kv_heads", "head_dim")
     v = logical.shard(v, "batch", "seq", "kv_heads", "head_dim")
-    q = common.apply_rope(q, positions, cfg.rope_theta)
-    k = common.apply_rope(k, positions, cfg.rope_theta)
+    q = common.apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = common.apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
     if cache is not None:
         cache_len = cache["k"].shape[1]
@@ -251,18 +253,84 @@ def encoder_kv(
 # ---------------------------------------------------------------------------
 
 
-def _mla_q(params, cfg: AttentionConfig, x, positions):
+def _mla_q(params, cfg: AttentionConfig, x, positions, eps):
     if cfg.q_lora_rank:
         cq = jnp.einsum("btd,dr->btr", x, params["w_dq"])
-        cq = common.rmsnorm(params["q_norm"], cq)
+        cq = common.rmsnorm(params["q_norm"], cq, eps)
         q = jnp.einsum("btr,rhx->bthx", cq, params["w_uq"])
     else:
         q = jnp.einsum("btd,dhx->bthx", x, params["w_q"])
     q_nope = q[..., : cfg.qk_nope_dim]
-    q_rope = common.apply_rope(q[..., cfg.qk_nope_dim :], positions, cfg.rope_theta)
+    q_rope = common.apply_rope(
+        q[..., cfg.qk_nope_dim :], positions, cfg.rope_theta, cfg.rope_scaling
+    )
     return q_nope, q_rope
 
 
+def mla_softmax_scale(cfg: AttentionConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor, mscale_all_dim)
+    squared where the rope scaling states ``mscale_all_dim``."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= common.yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+MLA_QUERY_BLOCK = 128  # queries per block of the expanded form's scores
+
+
+def mla_context_expanded(params, q_nope, q_rope, c_all, krope_all, bias, scale):
+    """Per-head K and V rebuilt from the latent at every cached position:
+    (B,T,H,nope), (B,T,H,rope) queries against (B,C,lora), (B,C,rope) ->
+    context (B,T,H,v) f32.  ``bias``: (B,1,T,C) additive mask.  Queries run
+    in blocks of at most ``MLA_QUERY_BLOCK`` (the last zero-padded), so the
+    f32 scores held at once are (B,H,block,C), not (B,H,T,C)."""
+    k_nope = jnp.einsum("bcr,rhn->bchn", c_all, params["w_uk"]).astype(jnp.float32)
+    vv = jnp.einsum("bcr,rhv->bchv", c_all, params["w_uv"]).astype(jnp.float32)
+    krope_all = krope_all.astype(jnp.float32)
+
+    def block(args):
+        qn, qr, bs = args
+        scores = jnp.einsum("bthn,bchn->bhtc", qn.astype(jnp.float32), k_nope)
+        scores += jnp.einsum("bthp,bcp->bhtc", qr.astype(jnp.float32), krope_all)
+        probs = jax.nn.softmax(scores * scale + bs, axis=-1)
+        return jnp.einsum("bhtc,bchv->bthv", probs, vv)
+
+    t = q_nope.shape[1]
+    n = -(-t // MLA_QUERY_BLOCK)
+    if n == 1:
+        return block((q_nope, q_rope, bias))
+    tb = -(-t // n)
+    pad = n * tb - t
+
+    def blocks(x, axis):
+        x = jnp.pad(x, [(0, pad if i == axis else 0) for i in range(x.ndim)])
+        x = x.reshape(x.shape[:axis] + (n, tb) + x.shape[axis + 1 :])
+        return jnp.moveaxis(x, axis, 0)
+
+    ctx = jax.lax.map(block, (blocks(q_nope, 1), blocks(q_rope, 1), blocks(bias, 2)))
+    ctx = jnp.moveaxis(ctx, 0, 1)  # (B, n, tb, H, v)
+    return ctx.reshape((ctx.shape[0], n * tb) + ctx.shape[3:])[:, :t]
+
+
+def mla_context_absorbed(params, q_nope, q_rope, c_all, krope_all, bias, scale):
+    """The same context with W_uk folded into the query and W_uv applied
+    after the latent context: scores and context live in the latent space,
+    so nothing is expanded over the cache."""
+    q_lat = jnp.einsum(
+        "bthn,rhn->bthr", q_nope.astype(jnp.float32), params["w_uk"].astype(jnp.float32)
+    )
+    scores = jnp.einsum("bthr,bcr->bhtc", q_lat, c_all.astype(jnp.float32))
+    scores += jnp.einsum(
+        "bthp,bcp->bhtc", q_rope.astype(jnp.float32), krope_all.astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(scores * scale + bias, axis=-1)
+    ctx_lat = jnp.einsum("bhtc,bcr->bthr", probs, c_all.astype(jnp.float32))
+    return jnp.einsum("bthr,rhv->bthv", ctx_lat, params["w_uv"].astype(jnp.float32))
+
+
+@telemetry.scoped("repro.mla")
 def mla_apply(
     params: dict,
     cfg: AttentionConfig,
@@ -270,16 +338,21 @@ def mla_apply(
     positions: jax.Array,
     *,
     cache: Optional[dict] = None,
+    eps: float = 1e-5,
 ) -> tuple[jax.Array, Optional[dict]]:
+    """``eps``: the model's RMSNorm epsilon, for the latent norms.  One query
+    position against a cache runs ``mla_context_absorbed``; more positions, or
+    no cache, ``mla_context_expanded``."""
     b, t, _ = x.shape
     h = cfg.num_heads
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
 
-    q_nope, q_rope = _mla_q(params, cfg, x, positions)
+    q_nope, q_rope = _mla_q(params, cfg, x, positions, eps)
 
     dkv = jnp.einsum("btd,dr->btr", x, params["w_dkv"])
-    c_kv = common.rmsnorm(params["kv_norm"], dkv[..., : cfg.kv_lora_rank])
-    k_rope = common.apply_rope(dkv[..., cfg.kv_lora_rank :], positions, cfg.rope_theta)
+    c_kv = common.rmsnorm(params["kv_norm"], dkv[..., : cfg.kv_lora_rank], eps)
+    k_rope = common.apply_rope(
+        dkv[..., cfg.kv_lora_rank :], positions, cfg.rope_theta, cfg.rope_scaling
+    )
     c_kv = logical.shard(c_kv, "batch", "seq", "kv_lora")
 
     if cache is not None:
@@ -295,40 +368,17 @@ def mla_apply(
         c_all, krope_all, kv_pos = c_kv, k_rope, positions
 
     mask = _make_mask(positions, kv_pos, cfg.sliding_window)[:, None]  # (B,1,T,C)
-
-    if cfg.mla_absorb and cache is not None:
-        # Absorbed decode: score/context directly in the latent space.
-        q_lat = jnp.einsum(
-            "bthn,rhn->bthr", q_nope.astype(jnp.float32), params["w_uk"].astype(jnp.float32)
-        )
-        scores = jnp.einsum("bthr,bcr->bhtc", q_lat, c_all.astype(jnp.float32))
-        scores += jnp.einsum(
-            "bthp,bcp->bhtc", q_rope.astype(jnp.float32), krope_all.astype(jnp.float32)
-        )
-        scores = scores * scale + jnp.where(mask, 0.0, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx_lat = jnp.einsum("bhtc,bcr->bthr", probs, c_all.astype(jnp.float32))
-        ctx = jnp.einsum("bthr,rhv->bthv", ctx_lat, params["w_uv"].astype(jnp.float32))
-    else:
-        # Expanded path (training / prefill / naive decode baseline).
-        k_nope = jnp.einsum("bcr,rhn->bchn", c_all, params["w_uk"])
-        vv = jnp.einsum("bcr,rhv->bchv", c_all, params["w_uv"])
-        scores = jnp.einsum(
-            "bthn,bchn->bhtc", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32)
-        )
-        scores += jnp.einsum(
-            "bthp,bcp->bhtc", q_rope.astype(jnp.float32), krope_all.astype(jnp.float32)
-        )
-        scores = scores * scale + jnp.where(mask, 0.0, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhtc,bchv->bthv", probs, vv.astype(jnp.float32))
+    bias = jnp.where(mask, 0.0, NEG_INF)
+    context = mla_context_absorbed if cache is not None and t == 1 else mla_context_expanded
+    ctx = context(params, q_nope, q_rope, c_all, krope_all, bias, mla_softmax_scale(cfg))
 
     ctx = ctx.reshape(b, t, h * cfg.v_head_dim).astype(x.dtype)
     out = jnp.einsum("bte,ed->btd", ctx, params["w_o"])
     return logical.shard(out, "batch", "residual_seq", "embed"), cache
 
 
-def apply(params, cfg: AttentionConfig, x, positions, *, cache=None, causal=True):
+def apply(params, cfg: AttentionConfig, x, positions, *, cache=None, causal=True, eps=1e-5):
+    """``eps`` is the model's RMSNorm epsilon (MLA's latent norms use it)."""
     if cfg.kind == "mla":
-        return mla_apply(params, cfg, x, positions, cache=cache)
+        return mla_apply(params, cfg, x, positions, cache=cache, eps=eps)
     return gqa_apply(params, cfg, x, positions, cache=cache, causal=causal)

@@ -7,6 +7,7 @@ in f32 throughout.
 """
 from __future__ import annotations
 
+import math
 from typing import Sequence
 
 import jax
@@ -67,18 +68,54 @@ def layernorm(params: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D) or (..., S, D); positions: (..., S) int32."""
+def yarn_ramp_range(scaling, head_dim: int, theta: float) -> tuple[int, int]:
+    """The frequency indices [low, high] over which YaRN's ramp runs: the
+    indices that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context, floored and ceiled, clipped to [0, head_dim - 1]."""
+
+    def index(rotations):
+        turns = scaling.original_max_position / (rotations * 2 * math.pi)
+        return head_dim * math.log(turns) / (2 * math.log(theta))
+
+    low = math.floor(index(scaling.beta_fast))
+    high = math.ceil(index(scaling.beta_slow))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def rope_frequencies(head_dim: int, theta: float = 10000.0, scaling=None) -> jax.Array:
+    """(D/2,) inverse frequencies; YaRN-interpolated where ``scaling`` (a
+    ``configs.base.RopeScaling``) is given."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    low, high = yarn_ramp_range(scaling, head_dim, theta)
+    idx = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((idx - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0, scaling=None
+) -> jax.Array:
+    """x: (..., S, H, D) or (..., S, D); positions: (..., S) int32.  The two
+    halves of the last axis rotate together (rotate_half)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)  # (D/2,)
+    freqs = rope_frequencies(d, theta, scaling)  # (D/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, D/2)
     if x.ndim == angles.ndim + 1:  # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -17,8 +17,9 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.configs.base import ModelConfig
-from repro.models import common
+from repro.models import common, moe
 from repro.models import transformer as tf
 
 PyTree = Any
@@ -105,7 +106,14 @@ def build_sequence_classifier(cfg: ModelConfig, num_classes: int):
 
 
 def build_model(cfg: ModelConfig) -> Model:
+    """The family's functions for ``cfg``.  An expert model adds the bytes of
+    the routed experts it holds, over its layers, to the counter
+    ``moe.held_expert_bytes``."""
     fam = cfg.family
+    if cfg.moe is not None:
+        layers = cfg.num_layers - cfg.moe.first_dense_layers
+        telemetry.count("moe.held_expert_bytes", layers * moe.held_expert_bytes(
+            cfg.moe, cfg.d_model, tf.compute_dtype(cfg)))
 
     if fam in ("dense", "moe", "vlm"):
         init = lambda k: tf.decoder_init(k, cfg)

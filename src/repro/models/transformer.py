@@ -50,14 +50,18 @@ def _block_init(key, cfg: ModelConfig, *, use_moe: bool, dense_ff: Optional[int]
 
 
 def _block_apply(p, cfg: ModelConfig, x, positions, cache):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux).  With a cache (prefill, decode) the expert
+    layer drops no token; without one (training) it runs the capacity
+    dispatch that expert-parallel meshes partition."""
     h, cache = attention.apply(
-        p["attn"], cfg.attention, common.rmsnorm(p["ln1"], x, cfg.norm_eps), positions, cache=cache
+        p["attn"], cfg.attention, common.rmsnorm(p["ln1"], x, cfg.norm_eps), positions,
+        cache=cache, eps=cfg.norm_eps,
     )
     x = x + h
     h2 = common.rmsnorm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
-        h2, aux = moe.apply(p["moe"], cfg.moe, h2, act=cfg.act)
+        dispatch = moe.apply if cache is not None else moe.apply_capacity
+        h2, aux = dispatch(p["moe"], cfg.moe, h2, act=cfg.act)
     else:
         h2, aux = common.mlp_apply(p["mlp"], h2, act=cfg.act), jnp.zeros((), jnp.float32)
     return x + h2, cache, aux

@@ -17,13 +17,17 @@ Always on; nothing turns it off.
   ``metadata={op_name=...}``.  An instruction without a scope of its own
   takes the scope of the innermost scoped ``while``, ``call`` or
   ``conditional`` that runs it, so the copies XLA adds inside a scoped loop
-  count with that loop.
+  count with that loop; a custom call without one (a kernel the compiler
+  put in an op's place, such as the TPU's grouped product) first takes the
+  scope of its first operand that has one, so it counts with the op whose
+  data it consumes.
 
 Named scopes on the device side (``jax.named_scope``, or ``scoped(scope)``
 as a decorator): ``repro.local``, ``repro.consensus`` (``core/p2p.py``),
 ``repro.eval`` (``p2p.stratified_accuracy``), ``repro.route``,
 ``repro.prefill`` and ``repro.decode`` (``launch/serve.py``,
-``launch/steps.py``).  They add metadata only.
+``launch/steps.py``), ``repro.mla`` (``models/attention.py``) and
+``repro.moe`` (``models/moe.py``).  They add metadata only.
 """
 from __future__ import annotations
 
@@ -186,6 +190,8 @@ _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s*=\s*(.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SCOPE = re.compile(re.escape(SCOPE_PREFIX) + r"\w+")
 _CONTAINER = re.compile(r"\s(?:" + "|".join(CONTAINER_OPS) + r")\(")
+_CUSTOM_CALL = re.compile(r"\scustom-call\(([^)]*)\)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
 _CALLEES = re.compile(
     r"\b(?:body|condition|to_apply|true_computation|false_computation)=%?([\w.\-]+)"
     r"|\bbranch_computations=\{([^}]*)\}"
@@ -198,6 +204,7 @@ def hlo_scopes(hlo_text: str) -> dict[str, str | None]:
     own: dict[str, str | None] = {}  # instruction -> scope of its own op_name
     home: dict[str, str] = {}  # instruction -> its computation
     caller: dict[str, str] = {}  # computation -> the container instruction running it
+    operands: dict[str, list[str]] = {}  # custom call -> its operands
     comp = None
     for line in hlo_text.splitlines():
         if line.endswith("{") and not line[:1].isspace():
@@ -211,6 +218,9 @@ def hlo_scopes(hlo_text: str) -> dict[str, str | None]:
         op = _OP_NAME.search(rhs)
         scopes = _SCOPE.findall(op.group(1)) if op else []
         own[name] = scopes[-1] if scopes else None
+        call = _CUSTOM_CALL.search(rhs)
+        if call is not None:
+            operands[name] = _OPERAND.findall(call.group(1))
         if _CONTAINER.search(rhs):
             for single, branches in _CALLEES.findall(rhs):
                 for callee in [single] if single else branches.split(","):
@@ -222,6 +232,9 @@ def hlo_scopes(hlo_text: str) -> dict[str, str | None]:
         if instr in resolved:
             return resolved[instr]
         scope = own[instr]
+        if scope is None:
+            scope = next((own[o] for o in operands.get(instr, ())
+                          if own.get(o) is not None and home[o] == home[instr]), None)
         if scope is None:
             parent = caller.get(home[instr])
             scope = scope_of(parent) if parent is not None else None

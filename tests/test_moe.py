@@ -1,4 +1,5 @@
-"""MoE dispatch: grouped-capacity path vs dense oracle; capacity semantics."""
+"""MoE training dispatch: grouped-capacity path vs dense oracle; capacity
+semantics.  The serving path's dropless dispatch: tests/test_deepseek_v2_lite.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +22,7 @@ def test_grouped_matches_dense_reference_when_no_drops(groups, shared):
     """With a huge capacity factor nothing is dropped: exact match."""
     cfg, params = _setup(shared=shared, groups=groups, cf=64.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16), jnp.float32)
-    out_g, aux_g = moe.apply(params, cfg, x)
+    out_g, aux_g = moe.apply_capacity(params, cfg, x)
     out_d, aux_d = moe.apply_dense_reference(params, cfg, x)
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d), atol=1e-4)
     if groups == 1:
@@ -35,8 +36,8 @@ def test_capacity_drops_tokens():
     """Tiny capacity: output is a (strictly) partial version of the dense one."""
     cfg, params = _setup(cf=0.25)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 16), jnp.float32)
-    out_small, _ = moe.apply(params, cfg, x)
-    out_full, _ = moe.apply(params, cfg.__class__(**{**cfg.__dict__, "capacity_factor": 64.0}), x)
+    out_small, _ = moe.apply_capacity(params, cfg, x)
+    out_full, _ = moe.apply_capacity(params, cfg.__class__(**{**cfg.__dict__, "capacity_factor": 64.0}), x)
     # some tokens dropped -> outputs differ; but finite and same shape
     assert out_small.shape == out_full.shape
     assert np.isfinite(np.asarray(out_small)).all()
@@ -56,7 +57,7 @@ def test_aux_loss_uniform_router_is_one():
     # force uniform router
     params["router"] = jnp.zeros_like(params["router"])
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 64, 16), jnp.float32)
-    _, aux = moe.apply(params, cfg, x)
+    _, aux = moe.apply_capacity(params, cfg, x)
     assert 0.8 <= float(aux) <= 1.3
 
 
@@ -72,7 +73,7 @@ def test_gradients_flow_through_dispatch():
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, 16), jnp.float32)
 
     def loss(p):
-        out, aux = moe.apply(p, cfg, x)
+        out, aux = moe.apply_capacity(p, cfg, x)
         return jnp.sum(out**2) + 0.01 * aux
 
     grads = jax.grad(loss)(params)

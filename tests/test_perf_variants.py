@@ -42,24 +42,29 @@ def test_cache_layout_specs():
     assert specs.cache_leaf_spec(["c_kv"], 3, layout="seq") == P("data", "model", None)
 
 
-def test_mla_absorb_equals_expanded_decode(rng):
-    """mla_absorb=True decode logits == the expanded path (same params)."""
+def test_mla_absorb_equals_expanded_decode(rng, monkeypatch):
+    """Decode picks the absorbed form (one query position against a cache);
+    its logits equal those of the same step run in the expanded form."""
     from repro.configs import get_config, reduced
-    from repro.models import build_model
+    from repro.models import attention, build_model
 
     cfg = reduced(get_config("deepseek-v2-236b"))
-    cfg_abs = cfg.replace(attention=dataclasses.replace(cfg.attention, mla_absorb=True))
     m = build_model(cfg)
-    m_abs = build_model(cfg_abs)
     params = m.init(jax.random.PRNGKey(0))
     batch = m.make_batch(jax.random.PRNGKey(1), 2, 8)
     cache = m.init_cache(2, 12)
     _, cache = m.prefill(params, batch, cache)
-    cache2 = jax.tree.map(lambda x: x, cache)
     tok = jnp.asarray([3, 5], jnp.int32)
     pos = jnp.full((2,), 8, jnp.int32)
+
+    used = []
+    absorbed = attention.mla_context_absorbed
+    monkeypatch.setattr(attention, "mla_context_absorbed",
+                        lambda *a: used.append("absorbed") or absorbed(*a))
     logits1, _ = m.decode_step(params, tok, pos, cache)
-    logits2, _ = m_abs.decode_step(params, tok, pos, cache2)
+    assert used, "decode did not take the absorbed form"
+    monkeypatch.setattr(attention, "mla_context_absorbed", attention.mla_context_expanded)
+    logits2, _ = m.decode_step(params, tok, pos, cache)
     np.testing.assert_allclose(np.asarray(logits1), np.asarray(logits2), atol=2e-3, rtol=2e-3)
 
 

@@ -269,6 +269,34 @@ ENTRY %main (x: f32[4]) -> f32[4] {
     }
 
 
+def test_unscoped_custom_calls_take_the_scope_of_their_operands():
+    text = """HloModule m
+
+%body (p: (s32[], bf16[8,4])) -> (s32[], bf16[8,4]) {
+  %p = (s32[], bf16[8,4]{1,0}) parameter(0)
+  %gte = bf16[8,4]{1,0} get-tuple-element(%p), index=1
+  %sizes = s32[2]{0} fusion(%gte), kind=kLoop, calls=%f1, metadata={op_name="jit(f)/repro.prefill/repro.moe/reduce_sum"}
+  %meta = (s32[2]{0}, s32[1]{0}) custom-call(%sizes), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %gte.1 = s32[2]{0} get-tuple-element(%meta), index=0
+  %rows = bf16[8,4]{1,0} fusion(%gte), kind=kLoop, calls=%f2, metadata={op_name="jit(f)/repro.prefill/repro.moe/gather"}
+  %ragged-dot-none = bf16[8,4]{1,0} custom-call(%gte.1, %rows, %gte), custom_call_target="tpu_custom_call"
+  %other = bf16[8,4]{1,0} custom-call(%gte), custom_call_target="tpu_custom_call"
+  %copy.2 = bf16[8,4]{1,0} copy(%rows)
+  ROOT %tuple = (s32[], bf16[8,4]{1,0}) tuple(%c, %ragged-dot-none)
+}
+
+ENTRY %main (x: bf16[8,4]) -> bf16[8,4] {
+  %x = bf16[8,4]{1,0} parameter(0)
+  ROOT %while.3 = (s32[], bf16[8,4]{1,0}) while(%t), condition=%cond, body=%body, metadata={op_name="jit(f)/repro.prefill/while"}
+}
+"""
+    scopes = telemetry.hlo_scopes(text)
+    # a custom call takes its first scoped operand's scope
+    assert scopes["meta"] == scopes["ragged-dot-none"] == "repro.moe"
+    # without one, and for any other op, the loop's
+    assert scopes["other"] == scopes["copy.2"] == scopes["gte.1"] == "repro.prefill"
+
+
 def test_a_program_traces_once_for_many_same_shape_calls():
     drive, state, batches = _drive_and_batches()
     for _ in range(3):

@@ -211,3 +211,25 @@ def test_gqa_flash_attention_compiles_at_smollm_heads(one_chip):
     fn = functools.partial(gqa_flash_attention, causal=True, interpret=False)
     compiled = jax.jit(fn).lower(sds(att.num_heads), sds(att.num_kv_heads), sds(att.num_kv_heads))
     _assert_kernel(compiled.compile())
+
+
+def test_grouped_product_counts_with_the_expert_layer(one_chip):
+    """Above ``DENSE_DISPATCH_MAX_TOKENS`` tokens the dropless expert layer
+    runs the TPU's grouped product, a custom call without op metadata: every
+    one of its instructions maps to ``repro.moe`` by its operands."""
+    from repro import telemetry
+    from repro.configs.base import MoEConfig
+    from repro.models import moe
+
+    cfg = MoEConfig(num_experts=64, top_k=6, expert_ff=128, num_shared=2, experts_held=8,
+                    norm_topk_prob=False)
+    params = jax.eval_shape(lambda k: moe.init(k, 256, cfg, jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2, moe.DENSE_DISPATCH_MAX_TOKENS, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    fn = lambda p, x: moe.apply(p, cfg, x)[0]
+    text = jax.jit(fn).lower(_shapes(params, one_chip), x).compile().as_text()
+    scopes = telemetry.hlo_scopes(text)
+    grouped = [name for name in scopes if name.startswith("ragged-dot")]
+    assert len(grouped) >= 3  # gate, up and down products
+    assert {scopes[name] for name in grouped} == {"repro.moe"}
