@@ -4,7 +4,10 @@ Cache layout is unified: every cache carries ``pos_ids`` — the absolute
 position stored in each slot (-1 = empty).  Full-causal caches have
 ``cache_len = max_seq``; sliding-window caches are ring buffers of
 ``cache_len = window`` (write slot = pos % window), which is what makes
-``long_500k`` decode O(window) in memory for attention archs.
+``long_500k`` decode O(window) in memory for attention archs.  A decoder's
+layer scan hands attention the stack of every layer's cache and the layer's
+index: only the new positions are written, in place, and attention reads the
+layer's slab with them in it.
 
 MLA caches the *latent* (c_kv, k_rope) — the paper-faithful DeepSeek-V2
 design.  One query position against a cache (decode) runs the weight-absorbed
@@ -133,10 +136,27 @@ def _write_slots(cache_len: int, positions: jax.Array) -> jax.Array:
     return positions % cache_len
 
 
-def _scatter_cache(buf: jax.Array, slots: jax.Array, values: jax.Array) -> jax.Array:
-    """buf: (B, C, ...); slots: (B, T); values: (B, T, ...)."""
-    bidx = jnp.arange(buf.shape[0])[:, None]
-    return buf.at[bidx, slots].set(values.astype(buf.dtype))
+def _scatter_cache(buf: jax.Array, slots: jax.Array, values: jax.Array, layer=None) -> jax.Array:
+    """buf: (B, C, ...), or with ``layer`` every layer's stack (L, B, C, ...),
+    written in place at [layer, row, slot]; slots: (B, T); values: (B, T, ...)."""
+    bidx = jnp.arange(slots.shape[0])[:, None]
+    index = (bidx, slots) if layer is None else (layer, bidx, slots)
+    return buf.at[index].set(values.astype(buf.dtype))
+
+
+def _write_cache(cache: dict, layer, positions: jax.Array, entries: dict) -> tuple[dict, dict]:
+    """Write ``entries`` (each (B, T, ...)) and ``positions`` into their ring
+    slots.  Returns (the cache, this layer's view of it).  With ``layer`` the
+    cache is the stack of every layer's and only the new positions are
+    written; the view is that layer's slab, the new positions in it."""
+    slots = _write_slots(cache["pos_ids"].shape[-1], positions)
+    entries = dict(entries, pos_ids=positions)
+    cache = {name: _scatter_cache(buf, slots, entries[name], layer) for name, buf in cache.items()}
+    if layer is None:
+        return cache, cache
+    view = {name: jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+            for name, buf in cache.items()}
+    return cache, view
 
 
 # ---------------------------------------------------------------------------
@@ -168,9 +188,12 @@ def gqa_apply(
     positions: jax.Array,
     *,
     cache: Optional[dict] = None,
+    layer: Optional[jax.Array] = None,
     causal: bool = True,
 ) -> tuple[jax.Array, Optional[dict]]:
-    """x: (B, T, D); positions: (B, T) absolute. Returns (out, new_cache)."""
+    """x: (B, T, D); positions: (B, T) absolute. Returns (out, new_cache).
+    With ``layer``, ``cache`` is every layer's stack (L, B, C, ...) and this
+    layer's new positions are written into it at [layer, row, slot]."""
     b, t, _ = x.shape
     h, kh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kh
@@ -187,28 +210,17 @@ def gqa_apply(
     k = common.apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
     if cache is not None:
-        cache_len = cache["k"].shape[1]
-        slots = _write_slots(cache_len, positions)
         if "k_scale" in cache:  # int8-quantized cache
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            cache = {
-                "k": _scatter_cache(cache["k"], slots, kq),
-                "v": _scatter_cache(cache["v"], slots, vq),
-                "k_scale": _scatter_cache(cache["k_scale"], slots, ks),
-                "v_scale": _scatter_cache(cache["v_scale"], slots, vs),
-                "pos_ids": _scatter_cache(cache["pos_ids"], slots, positions),
-            }
-            kk = _dequantize_kv(cache["k"], cache["k_scale"])
-            vv = _dequantize_kv(cache["v"], cache["v_scale"])
-            kv_pos = cache["pos_ids"]
+            (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+            cache, view = _write_cache(
+                cache, layer, positions, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+            )
+            kk = _dequantize_kv(view["k"], view["k_scale"])
+            vv = _dequantize_kv(view["v"], view["v_scale"])
         else:
-            cache = {
-                "k": _scatter_cache(cache["k"], slots, k),
-                "v": _scatter_cache(cache["v"], slots, v),
-                "pos_ids": _scatter_cache(cache["pos_ids"], slots, positions),
-            }
-            kk, vv, kv_pos = cache["k"], cache["v"], cache["pos_ids"]
+            cache, view = _write_cache(cache, layer, positions, {"k": k, "v": v})
+            kk, vv = view["k"], view["v"]
+        kv_pos = view["pos_ids"]
     else:
         kk, vv, kv_pos = k, v, positions
 
@@ -338,11 +350,13 @@ def mla_apply(
     positions: jax.Array,
     *,
     cache: Optional[dict] = None,
+    layer: Optional[jax.Array] = None,
     eps: float = 1e-5,
 ) -> tuple[jax.Array, Optional[dict]]:
-    """``eps``: the model's RMSNorm epsilon, for the latent norms.  One query
-    position against a cache runs ``mla_context_absorbed``; more positions, or
-    no cache, ``mla_context_expanded``."""
+    """``eps``: the model's RMSNorm epsilon, for the latent norms; ``layer``
+    as in ``gqa_apply``.  One query position against a cache runs
+    ``mla_context_absorbed``; more positions, or no cache,
+    ``mla_context_expanded``."""
     b, t, _ = x.shape
     h = cfg.num_heads
 
@@ -356,14 +370,8 @@ def mla_apply(
     c_kv = logical.shard(c_kv, "batch", "seq", "kv_lora")
 
     if cache is not None:
-        cache_len = cache["c_kv"].shape[1]
-        slots = _write_slots(cache_len, positions)
-        cache = {
-            "c_kv": _scatter_cache(cache["c_kv"], slots, c_kv),
-            "k_rope": _scatter_cache(cache["k_rope"], slots, k_rope),
-            "pos_ids": _scatter_cache(cache["pos_ids"], slots, positions),
-        }
-        c_all, krope_all, kv_pos = cache["c_kv"], cache["k_rope"], cache["pos_ids"]
+        cache, view = _write_cache(cache, layer, positions, {"c_kv": c_kv, "k_rope": k_rope})
+        c_all, krope_all, kv_pos = view["c_kv"], view["k_rope"], view["pos_ids"]
     else:
         c_all, krope_all, kv_pos = c_kv, k_rope, positions
 
@@ -377,8 +385,10 @@ def mla_apply(
     return logical.shard(out, "batch", "residual_seq", "embed"), cache
 
 
-def apply(params, cfg: AttentionConfig, x, positions, *, cache=None, causal=True, eps=1e-5):
-    """``eps`` is the model's RMSNorm epsilon (MLA's latent norms use it)."""
+def apply(params, cfg: AttentionConfig, x, positions, *, cache=None, layer=None, causal=True,
+          eps=1e-5):
+    """``eps`` is the model's RMSNorm epsilon (MLA's latent norms use it);
+    ``layer`` indexes a stacked ``cache`` (``gqa_apply``)."""
     if cfg.kind == "mla":
-        return mla_apply(params, cfg, x, positions, cache=cache, eps=eps)
-    return gqa_apply(params, cfg, x, positions, cache=cache, causal=causal)
+        return mla_apply(params, cfg, x, positions, cache=cache, layer=layer, eps=eps)
+    return gqa_apply(params, cfg, x, positions, cache=cache, layer=layer, causal=causal)

@@ -12,12 +12,13 @@ Per-family batch/IO contracts (see data/pipeline.py and launch/dryrun.py):
 """
 from __future__ import annotations
 
-import functools
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.configs.base import ModelConfig
 from repro.models import attention, common, moe, ssm
 from repro.sharding import logical
@@ -49,13 +50,14 @@ def _block_init(key, cfg: ModelConfig, *, use_moe: bool, dense_ff: Optional[int]
     return p
 
 
-def _block_apply(p, cfg: ModelConfig, x, positions, cache):
-    """Returns (x, new_cache, aux).  With a cache (prefill, decode) the expert
+def _block_apply(p, cfg: ModelConfig, x, positions, cache, layer=None):
+    """Returns (x, new_cache, aux).  ``layer`` indexes a stacked ``cache``
+    (``attention.gqa_apply``).  With a cache (prefill, decode) the expert
     layer drops no token; without one (training) it runs the capacity
     dispatch that expert-parallel meshes partition."""
     h, cache = attention.apply(
         p["attn"], cfg.attention, common.rmsnorm(p["ln1"], x, cfg.norm_eps), positions,
-        cache=cache, eps=cfg.norm_eps,
+        cache=cache, layer=layer, eps=cfg.norm_eps,
     )
     x = x + h
     h2 = common.rmsnorm(p["ln2"], x, cfg.norm_eps)
@@ -72,19 +74,24 @@ def _stacked_init(key, n, init_one):
 
 
 def _scan_blocks(block_fn, x, stacked_params, stacked_cache, remat: bool):
-    """scan over layers; carry (x, aux); xs = (params, cache); ys = cache."""
+    """scan over layers: ``block_fn(p, x, cache, layer) -> (x, cache, aux)``.
+
+    The stacked cache (every leaf (L, B, C, ...); None in training and in the
+    encoder) rides in the carry with the layer index, and each block writes
+    only its new positions into it, in place: nothing of the cache leaves the
+    scan as ``ys``.  So under the fleet's vmap the group axis stays in front
+    of the layer axis, as in the decode scan's carry, and a decode step
+    neither transposes the cache nor rewrites a layer's slab."""
     fn = jax.checkpoint(block_fn) if remat else block_fn
 
-    def body(carry, layer):
-        x, aux = carry
-        p, c = layer
-        x, c, a = fn(p, x, c)
-        return (x, aux + a), c
+    def body(carry, p):
+        x, aux, cache, layer = carry
+        x, cache, a = fn(p, x, cache, layer)
+        return (x, aux + a, cache, layer + 1), None
 
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (stacked_params, stacked_cache)
-    )
-    return x, new_cache, aux
+    carry = (x, jnp.zeros((), jnp.float32), stacked_cache, jnp.zeros((), jnp.int32))
+    (x, aux, cache, _), _ = jax.lax.scan(body, carry, stacked_params)
+    return x, cache, aux
 
 
 # ===========================================================================
@@ -124,42 +131,32 @@ def _decoder_embed(params, cfg: ModelConfig, tokens, patches=None):
     return x
 
 
-def _null_cache(stacked_params):
-    """Per-layer None caches are not scannable; use a zero-length dummy pytree."""
-    n = jax.tree.leaves(stacked_params)[0].shape[0]
-    return jnp.zeros((n, 0), jnp.int32)
-
-
-def _maybe_cache_to_none(c):
-    return None if isinstance(c, jax.Array) and c.ndim >= 1 and c.shape[-1] == 0 else c
-
-
-# The scanned block needs cache=None handled inside (dummy arrays flow through
-# scan in the no-cache training path).
-def _block_apply_cacheaware(p, cfg, x, positions, c):
-    c = _maybe_cache_to_none(c)
-    x, c2, aux = _block_apply(p, cfg, x, positions, c)
-    if c2 is None:
-        c2 = jnp.zeros((0,), jnp.int32)
-    return x, c2, aux
+def _cache_write_bytes(caches, t: int) -> int:
+    """Bytes a pass of ``t`` positions writes into the stacked caches: every
+    leaf is (L, B, C, ...), and each of its L x B rows takes t entries."""
+    return sum(
+        leaf.shape[0] * leaf.shape[1] * t * math.prod(leaf.shape[3:]) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(caches)
+    )
 
 
 def _decoder_trunk(params, cfg: ModelConfig, x, positions, caches):
-    """caches: {"first": ..., "main": ...} stacked, or None (training)."""
+    """caches: {"first": ..., "main": ...} stacked, or None (training).  Each
+    trace with caches adds the bytes it writes into them to the counter
+    ``decode.cache_write_bytes``."""
+    if caches is not None:
+        telemetry.count("decode.cache_write_bytes", _cache_write_bytes(caches, x.shape[1]))
     aux = jnp.zeros((), jnp.float32)
     new_caches: dict | None = {} if caches is not None else None
-    block = lambda p, h, c: _block_apply_cacheaware(p, cfg, h, positions, c)
-    if "first_layers" in params:
-        c = caches["first"] if caches is not None else _null_cache(params["first_layers"])
-        x, nc, a = _scan_blocks(block, x, params["first_layers"], c, cfg.remat)
+    block = lambda p, h, c, layer: _block_apply(p, cfg, h, positions, c, layer)
+    for name, layers in (("first", "first_layers"), ("main", "layers")):
+        if layers not in params:
+            continue
+        c = None if caches is None else caches[name]
+        x, nc, a = _scan_blocks(block, x, params[layers], c, cfg.remat)
         aux = aux + a
         if new_caches is not None:
-            new_caches["first"] = nc
-    c = caches["main"] if caches is not None else _null_cache(params["layers"])
-    x, nc, a = _scan_blocks(block, x, params["layers"], c, cfg.remat)
-    aux = aux + a
-    if new_caches is not None:
-        new_caches["main"] = nc
+            new_caches[name] = nc
     x = common.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x, new_caches, aux
 
@@ -513,18 +510,17 @@ def encdec_encode(params, cfg: ModelConfig, frames):
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
-    def block(p, h, c):
-        del c
+    def block(p, h, cache, layer):
+        del cache, layer
         h2, _ = attention.apply(
             p["attn"], cfg.attention, common.rmsnorm(p["ln1"], h, cfg.norm_eps), positions,
             causal=False,
         )
         h = h + h2
         h = h + common.mlp_apply(p["mlp"], common.rmsnorm(p["ln2"], h, cfg.norm_eps), act=cfg.act)
-        return h, jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.float32)
+        return h, None, jnp.zeros((), jnp.float32)
 
-    dummy = jnp.zeros((cfg.encoder_layers, 0), jnp.int32)
-    x, _, _ = _scan_blocks(block, x, params["enc_layers"], dummy, cfg.remat)
+    x, _, _ = _scan_blocks(block, x, params["enc_layers"], None, cfg.remat)
     return common.rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
 
@@ -551,17 +547,10 @@ def encdec_cross_kv(params, cfg: ModelConfig, enc_out):
 
 
 def _encdec_dec_trunk(params, cfg: ModelConfig, x, positions, caches, cross_kv):
-    def body(carry, layer):
-        h = carry
-        p, c, kv = layer
-        c = _maybe_cache_to_none(c)
-        h, c2 = _encdec_dec_block(p, cfg, h, positions, c, kv)
-        if c2 is None:
-            c2 = jnp.zeros((0,), jnp.int32)
-        return h, c2
+    def body(h, layer):
+        p, c, kv = layer  # c is None without caches (training)
+        return _encdec_dec_block(p, cfg, h, positions, c, kv)
 
-    if caches is None:
-        caches = jnp.zeros((cfg.num_layers, 0), jnp.int32)
     body_fn = jax.checkpoint(body) if cfg.remat else body
     x, new_caches = jax.lax.scan(body_fn, x, (params["dec_layers"], caches, cross_kv))
     return common.rmsnorm(params["final_norm"], x, cfg.norm_eps), new_caches

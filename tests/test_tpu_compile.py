@@ -14,6 +14,7 @@ CPU backend the tests run on).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -233,3 +234,97 @@ def test_grouped_product_counts_with_the_expert_layer(one_chip):
     grouped = [name for name in scopes if name.startswith("ragged-dot")]
     assert len(grouped) >= 3  # gate, up and down products
     assert {scopes[name] for name in grouped} == {"repro.moe"}
+
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s*=\s*(\w+)\[([\d,]*)\]\S*\s+([\w\-]+)\(([^)]*)\)(.*)$"
+)
+_HLO_CALLEE = re.compile(r"\b(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+
+
+def _hlo_computations(text):
+    """{computation: {"ops": {name: (dtype, dims, opcode, operands, attrs)},
+    "root": name, "callees": [...], "whiles": [(op_name, body)]}} of an HLO
+    module's text; instructions of tuple shape are left out of "ops"."""
+    comps, comp = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = comps.setdefault(re.match(r"^(?:ENTRY\s+)?%?([^\s(]+)", line).group(1),
+                                    {"ops": {}, "root": None, "callees": [], "whiles": []})
+            continue
+        if comp is None or not line.startswith(" "):
+            continue
+        comp["callees"] += _HLO_CALLEE.findall(line)
+        if " while(" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+            comp["whiles"].append((op_name.group(1) if op_name else "", body))
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            name, dtype, dims, opcode, operands, attrs = m.groups()
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            comp["ops"][name] = (dtype, dims, opcode, re.findall(r"%([\w.\-]+)", operands), attrs)
+            if line.lstrip().startswith("ROOT"):
+                comp["root"] = name
+    return comps
+
+
+def _cache_sized_ops_in_loops(text, scope, dims_of_stacks):
+    """(opcode, update dims) of every instruction, in the loops traced under
+    ``scope`` and all they call, whose output has the dims of a whole stacked
+    cache.  An update is a scatter's or dynamic-update-slice's new values,
+    also as the root of a fusion; other opcodes give None."""
+    comps = _hlo_computations(text)
+    todo = [body for c in comps.values() for op_name, body in c["whiles"] if scope in op_name]
+    seen, found = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += comps[name]["callees"]
+        for _, (_, dims, opcode, operands, attrs) in comps[name]["ops"].items():
+            if dims not in dims_of_stacks or opcode in ("parameter", "get-tuple-element",
+                                                        "bitcast"):
+                continue
+            ops = comps[name]["ops"]
+            if opcode == "fusion":
+                fused = comps[_HLO_CALLEE.search(attrs).group(1)]
+                ops, root = fused["ops"], fused["ops"][fused["root"]]
+                opcode, operands = f"fusion:{root[2]}", root[3]
+            update = {"scatter": 2, "dynamic-update-slice": 1}.get(opcode.split(":")[-1])
+            found.append((opcode, None if update is None else ops[operands[update]][1]))
+    return found
+
+
+def test_fleet_decode_writes_one_token_per_row_in_place(one_chip):
+    """SmolLM's attention (3 KV heads of 64) at small depth and width, as
+    the fleet serves it: groups vmapped, the cache donated.  Inside the
+    decode loop nothing the size of a whole stacked cache is copied,
+    transposed or allocated; the only such ops update it in place, with one
+    token of every row of every group."""
+    from repro.configs.base import AttentionConfig, ModelConfig
+    from repro.launch import serve
+    from repro.models import build_model
+
+    groups, rows, prompt, gen = 2, 4, 12, 5
+    cfg = ModelConfig(name="smollm-small", family="dense", num_layers=3, d_model=128, d_ff=256,
+                      vocab_size=512, attention=AttentionConfig(num_heads=9, num_kv_heads=3,
+                                                                head_dim=64),
+                      tie_embeddings=True, dtype="bfloat16")
+    model = build_model(cfg)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    params = jax.eval_shape(jax.vmap(model.init), keys)
+    caches = jax.eval_shape(
+        lambda: serve.stack_request_caches(model.init_cache(rows, prompt + gen), groups))
+    prompts = {"tokens": jax.ShapeDtypeStruct((groups, rows, prompt), jnp.int32)}
+    peer_ids = jax.ShapeDtypeStruct((groups,), jnp.int32)
+    fleet = jax.jit(serve.make_fleet_generate_fn(model, gen), donate_argnums=(2,))
+    text = fleet.lower(*_shapes((params, prompts, caches, peer_ids), one_chip)).compile().as_text()
+
+    stacks = {leaf.shape for leaf in jax.tree.leaves(caches)}
+    assert {(groups, 3, rows, prompt + gen, 3, 64), (groups, 3, rows, prompt + gen)} == stacks
+    found = _cache_sized_ops_in_loops(text, "repro.decode", stacks)
+    assert {opcode for opcode, _ in found} <= {"scatter", "fusion:scatter"}, found
+    # K or V, and the position ids: one token of each row of each group
+    assert {update for _, update in found} == {(groups, rows, 3, 64), (groups, rows)}
