@@ -10,7 +10,6 @@ from repro.compression.compressors import (
     TopKPayload,
     compressor_names,
     ef_compress_leaf,
-    ef_compress_tree,
     from_config,
     get_compressor,
     register_compressor,
@@ -26,7 +25,6 @@ __all__ = [
     "TopKPayload",
     "compressor_names",
     "ef_compress_leaf",
-    "ef_compress_tree",
     "from_config",
     "get_compressor",
     "register_compressor",

@@ -222,25 +222,6 @@ def ef_compress_leaf(
     return payload, est + comp.decompress(payload, x)
 
 
-def ef_compress_tree(
-    comp: Compressor, params: PyTree, est: PyTree
-) -> tuple[list, PyTree]:
-    """``ef_compress_leaf`` over a stacked parameter tree.
-
-    Returns ``(payloads, est_new_tree)``; ``payloads`` is a list aligned with
-    ``jax.tree.leaves(params)`` (payload NamedTuples are pytrees themselves,
-    so they cannot ride inside a ``tree.map`` over params).
-    """
-    leaves, treedef = jax.tree.flatten(params)
-    e_leaves = jax.tree.leaves(est)
-    payloads, ests = [], []
-    for x, e in zip(leaves, e_leaves):
-        p, en = ef_compress_leaf(comp, x, e)
-        payloads.append(p)
-        ests.append(en)
-    return payloads, jax.tree.unflatten(treedef, ests)
-
-
 # ---------------------------------------------------------------------------
 # Registry
 # ---------------------------------------------------------------------------

@@ -2,15 +2,18 @@
 
 Three execution forms of the same mathematical op — out_k = sum_j W[k,j] w_j:
 
-1. **Stacked einsum** (`mix_stacked`): peer parameters are a pytree whose
-   leaves carry a leading K axis. Used for CPU experiments (vmap runtime) and
-   for the ``peer_axis="data"`` sharded mode, where the K axis is sharded over
-   the mesh and XLA lowers the einsum into the appropriate collectives.
+1. **Stacked einsum** (`mix_stacked`, `mix_leaf`): peer parameters are a
+   pytree whose leaves carry a leading K axis. Used for CPU experiments (vmap
+   runtime) and for the ``peer_axis="data"`` sharded mode, where the K axis is
+   sharded over the mesh and XLA lowers the einsum into the appropriate
+   collectives.
 2. **Sparse gather** (`mix_sparse`): padded neighbor-index form; O(K * deg)
    instead of O(K^2). Feeds the Pallas `consensus_mix` kernel.
-3. **Mesh collectives** (`mix_psum`, `mix_ring`): explicit collectives inside
-   ``shard_map`` for ``peer_axis="pod"`` production mode — complete graphs map
-   to a weighted all-reduce, ring graphs to two collective-permutes.
+3. **Mesh gathers** (`gather_peer_leaf`, `ring_gather_slots`): inside
+   ``shard_map``, the neighbour rows a peer (``peer_axis="pod"``, one
+   ``ppermute`` per schedule lane) or a block of peers (hierarchical, a ring
+   of ``ppermute``s) needs, which the stacked forms then mix (see the
+   exchanges of ``repro.core.p2p``).
 
 All operate on arbitrary pytrees and preserve leaf dtypes (mixing is computed
 in float32 and cast back, matching how one would do it on TPU to avoid bf16
@@ -116,34 +119,6 @@ def mix_sparse(
 # ---------------------------------------------------------------------------
 
 
-def scatter_rows(
-    nbr_idx: jax.Array,  # (p, D) int32 — global column indices per row
-    nbr_w: jax.Array,  # (p, D) f32 — weights (0.0 at padding slots)
-    num_peers: int,
-    *,
-    row_ids: jax.Array | None = None,  # (p,) global row indices
-    self_w: jax.Array | None = None,  # (p,) diagonal values, if any
-) -> jax.Array:
-    """Scatter padded sparse rows into a dense (p, K) weight block.
-
-    The bridge between the degree-bounded ``graph.SparseSchedule`` operands
-    and the dense row einsum: real slots place their weight at (row, idx);
-    padding slots (idx == the row's own global index, weight 0.0) add +-0.0
-    onto the diagonal entry, so the result equals the dense matrix block the
-    sparse rows were extracted from — bit for bit, which is what lets the
-    hierarchical runtime's K <= 64 "bridge" mode keep fp32 parity with the
-    dense runtimes.
-    """
-    p = nbr_idx.shape[0]
-    rows = jnp.arange(p, dtype=jnp.int32)
-    block = jnp.zeros((p, num_peers), jnp.float32)
-    if self_w is not None:
-        if row_ids is None:
-            raise ValueError("self_w placement needs the global row_ids")
-        block = block.at[rows, row_ids].set(self_w.astype(jnp.float32))
-    return block.at[rows[:, None], nbr_idx].add(nbr_w.astype(jnp.float32))
-
-
 def ring_gather_slots(
     x_block: jax.Array,  # (p, ...) this device's contiguous block of rows
     nbr_idx: jax.Array,  # (p, D) int32 GLOBAL neighbor indices
@@ -209,12 +184,19 @@ def slot_sum(nbr_w: jax.Array, gathered: jax.Array) -> jax.Array:
 
 
 def gather_peer_leaf(v: jax.Array, axis_name: str, lanes, num_peers: int) -> jax.Array:
-    """One leaf of ``gather_peer_rows``: (1, ...) block -> stacked (K, ...).
+    """Rebuild the stacked (K, ...) peer array of one leaf inside a shard_map
+    block: (1, ...) block -> (K, ...).
 
-    Factored out so the sharded consensus phase can pipeline leaves — issuing
-    leaf ``i+1``'s ppermutes while leaf ``i`` is still mixing (see
-    ``repro.core.p2p.consensus_phase_sharded``) — without changing the
-    per-leaf arithmetic that the bit-parity contract pins down.
+    ``v`` is this peer's (1, ...) slice of the stacked peer axis; ``lanes`` is
+    a static ``graph.PermLane`` tuple (see ``edge_color_lanes``).  One
+    ppermute per lane sends the block along that lane's edges — the
+    schedule-aware sparse communication pattern.  Rows of peers this shard
+    never hears from stay ZERO; consumers multiply them by mixing weights
+    that are zero on exactly those rows, so the zeros never contribute (and
+    the reconstructed einsum stays bit-identical to the dense stacked form).
+    One leaf at a time, so the pod consensus phase can pipeline leaves —
+    issuing leaf ``i+1``'s ppermutes while leaf ``i`` is still mixing (see
+    ``repro.core.p2p._Lanes``).
     """
     my = jax.lax.axis_index(axis_name)
     full = jnp.zeros((num_peers,) + v.shape[1:], v.dtype)
@@ -225,83 +207,6 @@ def gather_peer_leaf(v: jax.Array, axis_name: str, lanes, num_peers: int) -> jax
         # sentinel src == num_peers marks "no payload this lane": dropped
         full = full.at[src].set(recv[0], mode="drop")
     return full
-
-
-def gather_peer_rows(block: PyTree, axis_name: str, lanes, num_peers: int) -> PyTree:
-    """Rebuild the stacked (K, ...) peer array inside a shard_map block.
-
-    ``block`` leaves are this peer's (1, ...) slice of the stacked peer axis;
-    ``lanes`` is a static ``graph.PermLane`` tuple (see ``edge_color_lanes``).
-    One ppermute per lane sends the block along that lane's edges — the
-    schedule-aware sparse communication pattern.  Rows of peers this shard
-    never hears from stay ZERO; consumers multiply them by mixing weights that
-    are zero on exactly those rows, so the zeros never contribute (and the
-    reconstructed einsum stays bit-identical to the dense stacked form).
-    """
-    return jax.tree.map(
-        lambda v: gather_peer_leaf(v, axis_name, lanes, num_peers), block
-    )
-
-
-def mix_psum(x: PyTree, axis_name: str, *, self_weight: float, peer_weight: float) -> PyTree:
-    """Complete-graph gossip with uniform weights as one weighted all-reduce.
-
-    out_k = self_weight * x_k + peer_weight * sum_{j != k} x_j
-          = (self_weight - peer_weight) * x_k + peer_weight * psum(x).
-    """
-
-    def leaf(v):
-        vf = v.astype(jnp.float32)
-        total = jax.lax.psum(vf, axis_name)
-        out = (self_weight - peer_weight) * vf + peer_weight * total
-        return out.astype(v.dtype)
-
-    return jax.tree.map(leaf, x)
-
-
-def mix_ring(
-    x: PyTree, axis_name: str, *, self_weight: float, left_weight: float, right_weight: float
-) -> PyTree:
-    """Ring-graph gossip: two collective_permutes + weighted sum."""
-    # axis size via the psum-of-1 identity (jax.lax.axis_size is not
-    # available on every supported jax version)
-    n = jax.lax.psum(1, axis_name)
-    fwd = [(i, (i + 1) % n) for i in range(n)]
-    bwd = [((i + 1) % n, i) for i in range(n)]
-
-    def leaf(v):
-        vf = v.astype(jnp.float32)
-        from_left = jax.lax.ppermute(vf, axis_name, perm=fwd)
-        from_right = jax.lax.ppermute(vf, axis_name, perm=bwd)
-        out = self_weight * vf + left_weight * from_left + right_weight * from_right
-        return out.astype(v.dtype)
-
-    return jax.tree.map(leaf, x)
-
-
-def mix_collective(
-    x: PyTree,
-    axis_name: str,
-    w_row: jax.Array,
-    *,
-    topology: str = "complete",
-) -> PyTree:
-    """General row of a mixing matrix applied across a mesh axis.
-
-    ``w_row`` is the (K,) weight row for *this* shard's peer index
-    (use jax.lax.axis_index to select).  Complete topology uses an all-gather;
-    sparse topologies should prefer mix_ring / mix_psum.
-    """
-    if topology == "complete":
-
-        def leaf(v):
-            vf = v.astype(jnp.float32)
-            allv = jax.lax.all_gather(vf, axis_name)  # (K, ...)
-            w = w_row.reshape((-1,) + (1,) * (allv.ndim - 1))
-            return jnp.sum(w * allv, axis=0).astype(v.dtype)
-
-        return jax.tree.map(leaf, x)
-    raise ValueError(f"mix_collective only supports complete topology, got {topology!r}")
 
 
 # ---------------------------------------------------------------------------

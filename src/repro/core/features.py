@@ -2,7 +2,7 @@
 
 Every pairwise "feature A does not compose with feature B" rejection in the
 repo lives here: the config layer (``P2PConfig.__post_init__``), the runtime
-builders (``p2p._make_hier_round_step`` via ``make_sharded_round_fn``), the
+builders (``p2p._make_round_step`` via ``make_sharded_round_fn``), the
 launcher (``launch.train.run_paper_experiment``), and the CLI argparse layer
 all call ``check()`` / ``check_config()`` and raise the SAME formatted
 message through ``format_violation`` — so the error a user sees is identical

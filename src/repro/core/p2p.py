@@ -17,7 +17,7 @@ Affinity biases (Sec. IV-A, "one possible choice", which Sec. V-C uses):
     b_k <- (1/S) w_k                         (computed during local phase)
 
 This module is the *stacked* runtime: every state leaf carries a leading K
-(peer) axis.  Two execution modes share the math bit for bit:
+(peer) axis.  Its execution modes share the math bit for bit:
 
   * ``make_round_fn`` — the K axis is vmapped (CPU experiments); the mix is a
     dense (K, K) einsum.
@@ -26,11 +26,16 @@ This module is the *stacked* runtime: every state leaf carries a leading K
     phases run embarrassingly parallel, and the schedule-aware mix lowers to
     ``ppermute`` sends along the round's edges (``graph.schedule_lanes``),
     leaf-pipelined so the next leaf's sends overlap the current leaf's mix.
-    See repro/launch/train.py (``--peer-axis pod``) for the production path
-    and repro/kernels/consensus_mix for the fused TPU kernel.
+    With ``peers_per_device > 1`` each slice holds a block of peers (the
+    hierarchical runtime).  See repro/launch/train.py (``--peer-axis pod``)
+    for the production path.
 
-Both modes dispatch one jitted round per call; ``make_scan_driver`` wraps
-EITHER round step in a ``lax.scan`` over a whole eval-period chunk of rounds
+Every mode runs the same round step (``_make_round_step``) and the same
+consensus phase (``consensus_phase``): what differs is the EXCHANGE, how a
+block of peers sees its neighbours (``_Exchange`` and its subclasses), and,
+picked from the config, the WIRE stage: what receivers see of a sender.
+Each mode dispatches one jitted round per call; ``make_scan_driver`` wraps
+the round step in a ``lax.scan`` over a whole eval-period chunk of rounds
 (donated state buffers, stacked per-round metrics) — one dispatch and at most
 one host transfer per chunk, bit-identical results.
 
@@ -428,21 +433,26 @@ def protocol_constants(
     cfg: P2PConfig, data_sizes: np.ndarray | None = None
 ) -> tuple[protocols_lib.ProtocolConstants, graph_lib.GraphSchedule]:
     """Stacked (R, K, K) round constants of the config's consensus protocol."""
+    sched = _checked_schedule(cfg)
+    consts = protocols_lib.get_protocol(cfg.protocol).constants(
+        sched, cfg.mixing, data_sizes=data_sizes,
+        consensus_step_size=cfg.consensus_step_size,
+    )
+    return consts, sched
+
+
+def _checked_schedule(cfg: P2PConfig) -> graph_lib.GraphSchedule:
+    """``build_schedule``, warning when the protocol is biased on it."""
     sched = build_schedule(cfg)
-    proto = protocols_lib.get_protocol(cfg.protocol)
-    if sched.directed and not proto.directed_capable:
+    if sched.directed and not protocols_lib.get_protocol(cfg.protocol).directed_capable:
         warnings.warn(
             f"protocol {cfg.protocol!r} on a directed schedule "
             f"({sched.name!r}): a row-stochastic consensus point is biased on "
             "asymmetric graphs — use protocol='push_sum' unless the bias is "
             "deliberate",
-            stacklevel=2,
+            stacklevel=3,
         )
-    consts = proto.constants(
-        sched, cfg.mixing, data_sizes=data_sizes,
-        consensus_step_size=cfg.consensus_step_size,
-    )
-    return consts, sched
+    return sched
 
 
 def init_state(
@@ -629,111 +639,109 @@ def local_phase(
 # ---------------------------------------------------------------------------
 
 
-@telemetry.scoped("repro.consensus")
-def consensus_phase(
-    state: P2PState,
-    cfg: P2PConfig,
-    consts: protocols_lib.ProtocolConstants,
-) -> P2PState:
-    """Run S consensus steps of the config's protocol; updates the affinity
-    bias d en route.
+class _Exchange(protocols_lib.StackedExchange):
+    """How a runtime sees its neighbours: the stacked (vmap) exchange, where
+    every block holds all K peers and nothing crosses a device.  The mesh
+    runtimes subclass it (``_Lanes``, ``_Bridge``, ``_Segment``).  Besides
+    ``rows``/``own``/``view`` (see ``protocols.StackedExchange``) the phase
+    uses:
 
-    ``consts`` is ONE round's (K, K) slice of the protocol constants (select
-    it from the stacked schedule with ``protocols.round_constants``).  The
-    affinity biases operate on the *de-biased* parameters for every protocol:
-    gossip parameters are their own estimates, and push_sum's ``mix`` divides
-    the mass back out before returning.
+    ``out(a)``        — this runtime's rows of a ``rows``-shaped result (the
+                        bridge slices its full-K products after the reduction);
+    ``everyone(a)``   — every peer's entries of a per-peer vector (pod: one
+                        ``all_gather`` of K scalars);
+    ``average(b, v)`` — the affinity average ``sum_j beta_kj v_j`` of a view.
     """
-    if cfg.consensus_steps == 0:
-        return state._replace(round_idx=state.round_idx + 1)
 
-    proto = protocols_lib.get_protocol(cfg.protocol)
-    comp = compression_lib.from_config(cfg)
-    if not comp.identity:
-        return _consensus_phase_compressed(state, cfg, consts, proto, comp)
-    if cfg.staleness_bound > 0:
-        return _consensus_phase_async(state, cfg, consts, proto)
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    # Peers whose beta row is all-zero (isolated this round — e.g. churned
-    # out of a time-varying schedule) have no neighbors to be biased toward:
-    # their d stays 0 rather than decaying toward the origin.
-    has_nbrs = jnp.sum(consts.beta, axis=1) > 0  # (K,)
-    for _ in range(cfg.consensus_steps):
-        if cfg.use_affinity_d:
-            # d_k <- (1/T) sum_j beta_kj (w_j - w_k), from the *incoming*
-            # neighbor parameters of this consensus step (Sec. IV-A).
-            nbr_avg = consensus_lib.mix_stacked(consts.beta, params)
-            d_bias = jax.tree.map(
-                lambda avg, w: jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (w.ndim - 1)),
-                    (avg - w) / cfg.local_steps,
-                    jnp.zeros_like(w),
-                ),
-                nbr_avg,
-                params,
-            )
-        proto_state, mixed = proto.mix(proto_state, params, consts)
-        if cfg.use_affinity_b:
-            mixed = jax.tree.map(
-                lambda m, b: m + cfg.eta_b * b, mixed, state.b_bias
-            )
-        params = mixed
+    def out(self, a: jax.Array) -> jax.Array:
+        """This runtime's rows of a ``rows``-shaped result."""
+        return a
 
-    return state._replace(
-        params=params, d_bias=d_bias, protocol=proto_state,
-        round_idx=state.round_idx + 1,
-    )
+    def everyone(self, a: jax.Array) -> jax.Array:
+        """Every peer's entries of a per-peer vector."""
+        return a
+
+    def average(self, beta_rows: jax.Array, view: jax.Array) -> jax.Array:
+        """The beta-weighted neighbour average of a dense view."""
+        return self.out(consensus_lib.mix_leaf(beta_rows, view))
 
 
-def _consensus_phase_compressed(
-    state: P2PState,
-    cfg: P2PConfig,
-    consts: protocols_lib.ProtocolConstants,
-    proto: protocols_lib.ConsensusProtocol,
-    comp: compression_lib.Compressor,
-) -> P2PState:
-    """``consensus_phase`` when consensus messages cross a compressed wire.
+class _Lanes(_Exchange):
+    """Pod: one peer per mesh slice; neighbour rows arrive through one
+    ``ppermute`` per ``PermLane`` (``consensus.gather_peer_leaf``), and the
+    mix is this peer's (1, K) row of the stacked einsum, which keeps the
+    runtimes fp32 bit-identical.  Leaves are double-buffered: leaf i+1's
+    lanes are issued before leaf i's view is consumed, and an
+    ``optimization_barrier`` pins the pair so XLA cannot serialize the
+    in-flight sends behind the compute."""
 
-    Each step: ship the compressed parameter-to-estimate difference
-    (``C(x - x̂)``), advance the public-estimate stack in
-    ``P2PState.compression`` by its decompression (``x̂ <- x̂ + D(payload)``
-    — CHOCO-SGD's estimate tracking, see ``repro.compression``; the stack is
-    warm-started at the initial parameters), and run the protocol's
-    ``mix_compressed`` — the CONVEX form: self term on the TRUE parameters
-    (never on the wire), off-diagonal terms on the dense estimates, a
-    contraction that estimate lag cannot destabilize.  The affinity bias d
-    runs on estimate differences, ``d = (sum_j beta_kj x̂_j - x̂_k) / T``:
-    what receivers actually know of each other.  Push-sum mass rides
-    uncompressed inside ``mix_compressed``.
-    """
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    est = state.compression
-    has_nbrs = jnp.sum(consts.beta, axis=1) > 0  # (K,)
-    for _ in range(cfg.consensus_steps):
-        _, est = compression_lib.ef_compress_tree(comp, params, est)
-        xhat = est
-        if cfg.use_affinity_d:
-            nbr_avg = consensus_lib.mix_stacked(consts.beta, xhat)
-            d_bias = jax.tree.map(
-                lambda avg, xh: jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (xh.ndim - 1)),
-                    (avg - xh) / cfg.local_steps,
-                    jnp.zeros_like(xh),
-                ),
-                nbr_avg,
-                xhat,
-            )
-        proto_state, mixed = proto.mix_compressed(proto_state, params, xhat, consts)
-        if cfg.use_affinity_b:
-            mixed = jax.tree.map(
-                lambda m, b: m + cfg.eta_b * b, mixed, state.b_bias
-            )
-        params = mixed
+    def __init__(self, axis_name: str, lanes, num_peers: int):
+        self.axis_name, self.lanes, self.num_peers = axis_name, lanes, num_peers
 
-    return state._replace(
-        params=params, d_bias=d_bias, protocol=proto_state,
-        compression=est, round_idx=state.round_idx + 1,
-    )
+    def rows(self, a: jax.Array) -> jax.Array:
+        """This peer's row."""
+        my = jax.lax.axis_index(self.axis_name)
+        return jax.lax.dynamic_slice_in_dim(a, my, 1, axis=0)
+
+    own = rows
+
+    def view(self, v: jax.Array) -> jax.Array:
+        """The (K, ...) reconstruction, zero rows for non-in-neighbours."""
+        return consensus_lib.gather_peer_leaf(
+            v, self.axis_name, self.lanes, self.num_peers
+        )
+
+    def everyone(self, a: jax.Array) -> jax.Array:
+        """All K peers' entries: one tiled ``all_gather``."""
+        return jax.lax.all_gather(a, self.axis_name, axis=0, tiled=True)
+
+    def with_own(self, full: jax.Array, x: jax.Array) -> jax.Array:
+        """``full`` with this peer's row replaced by its (1, ...) block."""
+        return full.at[jax.lax.axis_index(self.axis_name)].set(x[0])
+
+
+class _Bridge(_Exchange):
+    """Hierarchical, K <= 64: a (p, ...) block of peers per slice.  Each leaf
+    is all-gathered and the stacked runtime's FULL dense einsum runs before
+    this block's rows are sliced out: slicing after the reduction keeps every
+    bit, where (p, K)-row forms of narrow leaves (scalars, the push-sum mass)
+    reduce in another order and drift by an ulp.  The round's constants are
+    the sparse schedule densified losslessly."""
+
+    def __init__(self, axis_name: str, row0: jax.Array, block: int):
+        self.axis_name, self.row0, self.block = axis_name, row0, block
+
+    def own(self, a: jax.Array) -> jax.Array:
+        """This device's p rows."""
+        return jax.lax.dynamic_slice_in_dim(a, self.row0, self.block, axis=0)
+
+    out = own
+
+    def view(self, v: jax.Array) -> jax.Array:
+        """The all-gathered (K, ...) stack."""
+        return jax.lax.all_gather(v, self.axis_name, axis=0, tiled=True)
+
+
+class _Segment(_Exchange):
+    """Hierarchical, large K: each leaf's peer blocks stream around the ring
+    and only this block's (p, D, ...) neighbour slots are kept
+    (``consensus.ring_gather_slots``); the mix is a weighted slot sum over
+    the round's degree-bounded ``SparseRoundOps`` block.  O(K * D * feat /
+    devices) memory, no (K, K) or (K, feat) intermediate — allclose to the
+    dense forms, not bitwise (slot-ordered sums)."""
+
+    def __init__(self, axis_name: str, num_devices: int, nbr_idx: jax.Array):
+        self.axis_name, self.num_devices, self.nbr_idx = axis_name, num_devices, nbr_idx
+
+    def view(self, v: jax.Array) -> jax.Array:
+        """The (p, D, ...) neighbour slots."""
+        return consensus_lib.ring_gather_slots(
+            v, self.nbr_idx, self.axis_name, self.num_devices
+        )
+
+    def average(self, beta_rows: jax.Array, view: jax.Array) -> jax.Array:
+        """The slot-weighted neighbour average."""
+        return consensus_lib.slot_sum(beta_rows, view)
 
 
 def _staleness_delivery(
@@ -757,7 +765,7 @@ def _staleness_delivery(
                    weight multiplier of this round's mix (1.0 for fresh
                    snapshots).
 
-    Both runtimes call this on the same (K,) age vector (the pod runtime
+    Every runtime calls this on the same (K,) age vector (the pod runtime
     all-gathers its K scalar ages first), so the delivery pattern — and with
     it the round's effective mixing matrix — is identical across runtimes.
     """
@@ -771,75 +779,201 @@ def _staleness_delivery(
     return delivered, new_age, decay
 
 
-def _consensus_phase_async(
+def _snapshot_round(
     state: P2PState,
     cfg: P2PConfig,
     consts: protocols_lib.ProtocolConstants,
-    proto: protocols_lib.ConsensusProtocol,
-) -> P2PState:
-    """``consensus_phase`` under bounded-staleness delivery (vmap runtime).
+    stochasticity: str,
+    ex: _Exchange,
+) -> tuple[protocols_lib.ProtocolConstants, StalenessState]:
+    """The published-snapshot wire's once-a-round advance.
 
-    Each round: decide delivery per sender (``_staleness_delivery``), advance
-    the ``StalenessState`` buffer (``published`` rows of delivering senders
-    become their live post-local-phase parameters; ages reset or increment),
-    then run the S consensus steps on the BUFFER — every off-diagonal term
-    reads the sender's last published snapshot — with age-decayed weights
-    renormalized per the protocol's stochasticity
+    Decide delivery per sender (``_staleness_delivery``, on every peer's
+    age), refresh the ``published`` rows of delivering senders with their
+    live post-local-phase parameters, and decay the round's constants
     (``protocols.age_decayed_constants``): stale senders' outgoing weights
     shrink by ``staleness_decay ** age`` and the freed mass moves onto the
     diagonal, keeping gossip rows and push-sum columns stochastic, so
-    push-sum mass conservation survives stale delivery exactly.
-
-    The mix itself is the protocol's ``mix_compressed`` — the convex
-    self-on-true-params / off-diagonal-on-substitute split is the same
-    contraction whether the substitute is a compressed estimate or a stale
-    snapshot.  Delivery happens once per ROUND: all S steps of a round mix
-    the same buffer (a straggler cannot publish mid-round).  The affinity
-    bias d also reads the buffer with the decayed beta — receivers can only
-    be biased toward what they have actually received.
+    push-sum mass conservation survives stale delivery exactly.  Delivery
+    happens once per ROUND: all S steps mix the same buffer (a straggler
+    cannot publish mid-round).  Returns the decayed constants and the new
+    ``StalenessState``.
     """
     st: StalenessState = state.staleness
-    delivered, age, decay = _staleness_delivery(cfg, state.round_idx, st.age)
+    delivered, age, decay = _staleness_delivery(
+        cfg, state.round_idx, ex.everyone(st.age)
+    )
+    mine = ex.own(delivered)
     published = jax.tree.map(
-        lambda p, q: jnp.where(
-            delivered.reshape((-1,) + (1,) * (p.ndim - 1)), p, q
-        ),
+        lambda p, q: jnp.where(mine.reshape((-1,) + (1,) * (p.ndim - 1)), p, q),
         state.params,
         st.published,
     )
-    a_consts = protocols_lib.age_decayed_constants(
-        consts, decay, proto.stochasticity
+    consts = protocols_lib.age_decayed_constants(consts, decay, stochasticity)
+    return consts, StalenessState(published=published, age=ex.own(age))
+
+
+def _choco_advance(
+    comp: compression_lib.Compressor, x: jax.Array, est: jax.Array, ex: _Exchange
+) -> jax.Array:
+    """The CHOCO wire, one leaf: ship ``C(x - x̂)`` and advance the estimate.
+
+    This runtime's rows compress their difference to their own public
+    estimate; every runtime then advances EVERY row of the (K, ...) stack by
+    the same payloads (``x̂ <- x̂ + D(payload)``, CHOCO-SGD's estimate
+    tracking, see ``repro.compression``), including its own rows, which must
+    match what other devices hold for this sender.  On the pod runtime the
+    payload arrays are broadcast with one tiled ``all_gather`` each and the
+    stack stays replicated (``sharding.specs.peer_stacked_pspecs``).  That
+    broadcast is a SIMULATOR artifact: the modeled per-edge system stores
+    estimate rows only for each node's union in-neighbours and delivers
+    payloads on every union lane every step; rows outside the union stay
+    frozen and are never read (zero weights in every round), so its
+    read-observable dynamics equal this simulation, and
+    ``benchmarks.wire.estimate_gossip_bytes_per_round`` prices exactly that
+    union-lane traffic.
+    """
+    payload = jax.tree.map(ex.everyone, comp.compress(x - ex.own(est)))
+    return est + comp.decompress(payload, est)
+
+
+@telemetry.scoped("repro.consensus")
+def consensus_phase(
+    state: P2PState,
+    cfg: P2PConfig,
+    consts: protocols_lib.ProtocolConstants,
+    *,
+    exchange: _Exchange | None = None,
+) -> P2PState:
+    """Run S consensus steps of the config's protocol; updates the affinity
+    bias d en route.
+
+    ``consts`` is ONE round's (K, K) slice of the protocol constants (select
+    it from the stacked schedule with ``protocols.round_constants``), or this
+    device's ``SparseRoundOps`` block on the segment exchange.  ``exchange``
+    is the runtime's view of its neighbours (``None``: the stacked vmap
+    runtime); the round step builds it.  Per step and per leaf:
+
+      (a) the WIRE stage gives what receivers see of a sender, picked from
+          ``cfg``: the live parameters; the CHOCO estimates
+          (``compressor != "none"``, advanced every step by
+          ``_choco_advance``; d on estimate differences); or the published
+          snapshots (``staleness_bound > 0``, advanced once a round by
+          ``_snapshot_round``; d against the live parameters);
+      (b) the EXCHANGE gives this runtime's view of those senders;
+      (c) affinity d from the view, with the isolated-peer guard;
+      (d) the protocol's mix: the plain form on live parameters, the convex
+          split form (self term on the true block, off-diagonal terms on the
+          substitutes) on estimates and snapshots — except the pod CHOCO
+          mix, the plain row over the estimates with this peer's own row
+          replaced by its true block (allclose to the split form);
+      (e) ``eta_b * b``.
+
+    The affinity biases operate on the *de-biased* parameters for every
+    protocol: push_sum's mix divides the mass back out before returning.
+    """
+    return _consensus(state, cfg, consts, exchange or _Exchange())
+
+
+@telemetry.scoped("repro.consensus")
+def consensus_phase_hier(
+    state: P2PState,
+    cfg: P2PConfig,
+    consts,
+    *,
+    exchange: _Exchange,
+) -> P2PState:
+    """``consensus_phase`` on the hierarchical runtime (``_Bridge`` or
+    ``_Segment`` exchange): the same phase, entered under its own name so
+    the hierarchical step can be patched apart from the others."""
+    return _consensus(state, cfg, consts, exchange)
+
+
+def _consensus(
+    state: P2PState, cfg: P2PConfig, consts, ex: _Exchange
+) -> P2PState:
+    """The body of ``consensus_phase``: the one S-step loop."""
+    if cfg.consensus_steps == 0:
+        return state._replace(round_idx=state.round_idx + 1)
+
+    proto = protocols_lib.get_protocol(cfg.protocol)
+    comp = compression_lib.from_config(cfg)
+    wire = "choco" if not comp.identity else (
+        "snapshot" if cfg.staleness_bound > 0 else "live"
     )
     params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    # neighbor support is read from the UNDECAYED beta: decay shrinks weights
-    # but never disconnects a peer, so isolation (d = 0) matches the
-    # synchronous rule
-    has_nbrs = jnp.sum(consts.beta, axis=1) > 0  # (K,)
-    for _ in range(cfg.consensus_steps):
-        if cfg.use_affinity_d:
-            nbr_avg = consensus_lib.mix_stacked(a_consts.beta, published)
-            d_bias = jax.tree.map(
-                lambda avg, w: jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (w.ndim - 1)),
-                    (avg - w) / cfg.local_steps,
-                    jnp.zeros_like(w),
-                ),
-                nbr_avg,
-                params,
-            )
-        proto_state, mixed = proto.mix_compressed(
-            proto_state, params, published, a_consts
+    # Peers whose beta row is all-zero (isolated this round — e.g. churned
+    # out of a time-varying schedule) have no neighbors to be biased toward:
+    # their d stays 0 rather than decaying toward the origin.  Read from the
+    # UNDECAYED beta: staleness shrinks weights but never disconnects a peer.
+    beta = ex.rows(consts.beta)
+    has_nbrs = ex.out(jnp.sum(beta, axis=1) > 0)
+    carried = {}
+    if wire == "choco":
+        est = jax.tree.leaves(state.compression)  # (K, ...) each
+    elif wire == "snapshot":
+        consts, carried["staleness"] = _snapshot_round(
+            state, cfg, consts, proto.stochasticity, ex
         )
-        if cfg.use_affinity_b:
-            mixed = jax.tree.map(
-                lambda m, b: m + cfg.eta_b * b, mixed, state.b_bias
-            )
-        params = mixed
+        beta = ex.rows(consts.beta)  # decayed: leans toward fresher senders
+        # the buffer is fixed for the round: one exchange, not one per step
+        snapshots = [ex.view(p) for p in jax.tree.leaves(carried["staleness"].published)]
+    lanes = isinstance(ex, _Lanes)
+    split = wire == "snapshot" or (wire == "choco" and not lanes)
+    b_leaves = jax.tree.leaves(state.b_bias)
+    for _ in range(cfg.consensus_steps):
+        proto_state, ctx = proto.begin(proto_state, consts, ex, split=split)
+        leaves, treedef = jax.tree.flatten(params)
 
+        def sent(i):
+            """(a) + (b): this runtime's view of sender leaf i."""
+            if wire == "choco":
+                est[i] = _choco_advance(comp, leaves[i], est[i], ex)
+                return est[i]
+            return snapshots[i] if wire == "snapshot" else ex.view(leaves[i])
+
+        mixed, ds = [], []
+        nxt = sent(0) if leaves else None
+        for i, x in enumerate(leaves):
+            view, nxt = nxt, sent(i + 1) if i + 1 < len(leaves) else None
+            d_i = None
+            if cfg.use_affinity_d:
+                # (c) d_k <- (1/T) sum_j beta_kj (w_j - w_k), from what this
+                # peer received (Sec. IV-A)
+                own = ex.own(view) if wire == "choco" else x
+                d_i = jnp.where(
+                    has_nbrs.reshape((-1,) + (1,) * (x.ndim - 1)),
+                    (ex.average(beta, view) - own) / cfg.local_steps,
+                    jnp.zeros_like(x),
+                )
+            # (d)
+            if split:
+                m_i = proto.mix_split_leaf(ctx, x, view)
+            else:
+                m_i = proto.mix_leaf(
+                    ctx, x, ex.with_own(view, x) if wire == "choco" else view
+                )
+            m_i = ex.out(m_i)
+            if cfg.use_affinity_b:  # (e)
+                m_i = m_i + cfg.eta_b * b_leaves[i]
+            if nxt is not None and lanes and wire == "live":
+                # double-buffer: group the next leaf's in-flight lanes with
+                # this leaf's results so neither side is sunk past the other
+                if d_i is not None:
+                    nxt, m_i, d_i = jax.lax.optimization_barrier((nxt, m_i, d_i))
+                else:
+                    nxt, m_i = jax.lax.optimization_barrier((nxt, m_i))
+            mixed.append(m_i)
+            ds.append(d_i)
+        params = jax.tree.unflatten(treedef, mixed)
+        if cfg.use_affinity_d:
+            d_bias = jax.tree.unflatten(treedef, ds)
+
+    if wire == "choco":
+        carried["compression"] = jax.tree.unflatten(treedef, est)
     return state._replace(
         params=params, d_bias=d_bias, protocol=proto_state,
-        staleness=StalenessState(published=published, age=age),
-        round_idx=state.round_idx + 1,
+        round_idx=state.round_idx + 1, **carried,
     )
 
 
@@ -867,7 +1001,7 @@ def run_round(
 
 
 # ---------------------------------------------------------------------------
-# Sharded peer-axis runtime (shard_map over the mesh, peer_axis="pod")
+# The round step (every runtime)
 # ---------------------------------------------------------------------------
 
 
@@ -877,584 +1011,8 @@ def _shard_map_fn():
     return functools.partial(jax.shard_map, check_vma=False)
 
 
-@telemetry.scoped("repro.consensus")
-def consensus_phase_sharded(
-    state: P2PState,
-    cfg: P2PConfig,
-    consts: protocols_lib.ProtocolConstants,
-    *,
-    axis_name: str,
-    lanes,
-) -> P2PState:
-    """``consensus_phase`` inside a shard_map block: one peer per mesh slice.
-
-    Every ``P2PState`` leaf carries this peer's (1, ...) block of the stacked
-    axis; ``consts`` is the round's full (K, K) slice (replicated — protocol
-    matrices are tiny next to parameters).  Neighbor parameters arrive through
-    one ``ppermute`` per ``PermLane`` (``consensus.gather_peer_leaf``); the mix
-    is then this peer's (1, K) row of the same einsum the stacked runtime
-    computes, which keeps the two runtimes bit-identical in fp32.
-
-    The leaves are *pipelined* (double-buffered): leaf ``i+1``'s ppermute
-    lanes are issued before leaf ``i``'s reconstruction is consumed by its mix
-    matvec, and an ``optimization_barrier`` pins the pair so XLA's scheduler
-    cannot serialize the in-flight sends behind the compute.  On a real mesh
-    the lane traffic for the next leaf therefore hides behind the current
-    leaf's matvecs; the per-leaf arithmetic is untouched, so the fp32
-    bit-parity contract with the vmap runtime holds unchanged.
-    """
-    if cfg.consensus_steps == 0:
-        return state._replace(round_idx=state.round_idx + 1)
-
-    proto = protocols_lib.get_protocol(cfg.protocol)
-    comp = compression_lib.from_config(cfg)
-    if not comp.identity:
-        return _consensus_phase_sharded_compressed(
-            state, cfg, consts, proto, comp, axis_name=axis_name, lanes=lanes
-        )
-    if cfg.staleness_bound > 0:
-        return _consensus_phase_sharded_async(
-            state, cfg, consts, proto, axis_name=axis_name, lanes=lanes
-        )
-    k = consts.w.shape[-1]
-    my = jax.lax.axis_index(axis_name)
-    beta_row = jnp.take(consts.beta, my, axis=0)[None]  # (1, K)
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    has_nbrs = jnp.sum(beta_row, axis=1) > 0  # (1,)
-    b_bias_leaves = jax.tree.leaves(state.b_bias)
-    # a protocol written against the pre-scan interface (whole-tree
-    # ``mix_sharded`` override, no ``mix_sharded_begin``) still works: it runs
-    # the unpipelined whole-tree path instead of silently hitting the base
-    # class's NotImplementedError (or worse, ignoring its override)
-    legacy_mix = (
-        type(proto).mix_sharded_begin
-        is protocols_lib.ConsensusProtocol.mix_sharded_begin
-    )
-    if legacy_mix:
-        for _ in range(cfg.consensus_steps):
-            params_full = consensus_lib.gather_peer_rows(params, axis_name, lanes, k)
-            if cfg.use_affinity_d:
-                nbr_avg = consensus_lib.mix_stacked(beta_row, params_full)
-                d_bias = jax.tree.map(
-                    lambda avg, w: jnp.where(
-                        has_nbrs.reshape((-1,) + (1,) * (w.ndim - 1)),
-                        (avg - w) / cfg.local_steps,
-                        jnp.zeros_like(w),
-                    ),
-                    nbr_avg,
-                    params,
-                )
-            proto_state, mixed = proto.mix_sharded(
-                proto_state, params, params_full, consts.w,
-                axis_name=axis_name, lanes=lanes,
-            )
-            if cfg.use_affinity_b:
-                mixed = jax.tree.map(
-                    lambda m, b: m + cfg.eta_b * b, mixed, state.b_bias
-                )
-            params = mixed
-        return state._replace(
-            params=params, d_bias=d_bias, protocol=proto_state,
-            round_idx=state.round_idx + 1,
-        )
-
-    for _ in range(cfg.consensus_steps):
-        # scalar/context work once per step (push_sum's mass lane rides here)
-        proto_state, ctx = proto.mix_sharded_begin(
-            proto_state, consts.w, axis_name=axis_name, lanes=lanes
-        )
-        leaves, treedef = jax.tree.flatten(params)
-        mixed_leaves, d_leaves = [], []
-        nxt = (
-            consensus_lib.gather_peer_leaf(leaves[0], axis_name, lanes, k)
-            if leaves else None
-        )
-        for i, x in enumerate(leaves):
-            x_full = nxt
-            # issue leaf i+1's lanes BEFORE leaf i's reconstruction is consumed
-            nxt = (
-                consensus_lib.gather_peer_leaf(leaves[i + 1], axis_name, lanes, k)
-                if i + 1 < len(leaves) else None
-            )
-            d_i = None
-            if cfg.use_affinity_d:
-                # d_k <- (1/T) sum_j beta_kj (w_j - w_k); isolated peers
-                # (all-zero beta row this round) keep d = 0
-                nbr_avg = consensus_lib.mix_leaf(beta_row, x_full)
-                d_i = jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (x.ndim - 1)),
-                    (nbr_avg - x) / cfg.local_steps,
-                    jnp.zeros_like(x),
-                )
-            m_i = proto.mix_sharded_leaf(ctx, x, x_full)
-            if cfg.use_affinity_b:
-                m_i = m_i + cfg.eta_b * b_bias_leaves[i]
-            if nxt is not None:
-                # double-buffer: group the next leaf's in-flight lanes with
-                # this leaf's results so neither side is sunk past the other
-                if d_i is not None:
-                    nxt, m_i, d_i = jax.lax.optimization_barrier((nxt, m_i, d_i))
-                else:
-                    nxt, m_i = jax.lax.optimization_barrier((nxt, m_i))
-            mixed_leaves.append(m_i)
-            d_leaves.append(d_i)
-        params = jax.tree.unflatten(treedef, mixed_leaves)
-        if cfg.use_affinity_d:
-            d_bias = jax.tree.unflatten(treedef, d_leaves)
-
-    return state._replace(
-        params=params, d_bias=d_bias, protocol=proto_state,
-        round_idx=state.round_idx + 1,
-    )
-
-
-def _consensus_phase_sharded_compressed(
-    state: P2PState,
-    cfg: P2PConfig,
-    consts: protocols_lib.ProtocolConstants,
-    proto: protocols_lib.ConsensusProtocol,
-    comp: compression_lib.Compressor,
-    *,
-    axis_name: str,
-    lanes,
-) -> P2PState:
-    """``consensus_phase_sharded`` over a compressed wire.
-
-    What rides the wire changes: instead of each raw fp32 leaf, every array
-    of the leaf's compressed difference payload (top-k values + indices, or
-    int8 tensor + fp32 scale) is broadcast with one tiled ``all_gather`` per
-    payload array.  Broadcast — not the schedule's edge lanes — because the
-    CHOCO estimate stack demands it: each device holds the full (K, ...)
-    public-estimate stack REPLICATED in ``state.compression``
-    (``sharding.specs.peer_stacked_pspecs`` keeps it un-sharded), and the
-    replicas only stay consistent (provably so, for shard_map's replication
-    checker) if every device advances every row from the same payloads every
-    step.  This is the same semantics the vmap compressed runtime computes,
-    and the wire still never carries fp32 parameters.
-
-    The ``all_gather`` broadcast is a SIMULATOR artifact, not the modeled
-    traffic.  The modeled per-edge system stores estimate rows only for each
-    node's union in-neighbors and delivers payloads on every union lane of
-    the schedule every step (active or not — sender and receiver copies of
-    ``x̂`` must advance in lockstep); rows outside the union stay frozen at
-    the warm start and are never read, because their mixing and affinity
-    weights are zero in every round.  Its read-observable dynamics are
-    therefore identical to this simulation, and the analytic bytes model
-    prices exactly that standing union-lane traffic
-    (``benchmarks.wire.estimate_gossip_bytes_per_round``), not the K*(K-1)
-    gather.
-
-    After advancing the stack, the receiver substitutes its TRUE block for
-    its own row of a TEMPORARY copy of the stack (the convex mix's self term
-    is exact under any compressor; the carried estimate itself advances only
-    from payloads, so replicas stay consistent) and applies the protocol's
-    ordinary ``mix_sharded_leaf`` row arithmetic.  ``mix_sharded_begin`` is
-    untouched: push-sum's scalar mass lane stays uncompressed, so mass
-    conservation is exact.
-
-    Numerics note: this path is allclose — not bit-identical — to the vmap
-    compressed path (a (1, K)-row einsum on the estimate vs. the stacked
-    diag/off-diag split).  The bit-parity contract of the pod runtime applies
-    to ``compressor="none"``, which never enters here.
-    """
-    my = jax.lax.axis_index(axis_name)
-    beta_row = jnp.take(consts.beta, my, axis=0)[None]  # (1, K)
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    has_nbrs = jnp.sum(beta_row, axis=1) > 0  # (1,)
-    b_bias_leaves = jax.tree.leaves(state.b_bias)
-    leaves, treedef = jax.tree.flatten(params)
-    e_leaves = jax.tree.leaves(state.compression)  # each (K, ...) replicated
-    for _ in range(cfg.consensus_steps):
-        # push-sum's scalar mass lane rides the schedule's edge lanes,
-        # uncompressed, exactly as on the identity path
-        proto_state, ctx = proto.mix_sharded_begin(
-            proto_state, consts.w, axis_name=axis_name, lanes=lanes
-        )
-        mixed_leaves, d_leaves, new_e = [], [], []
-        for i, x in enumerate(leaves):
-            est = e_leaves[i]
-            # sender side: this peer's difference to its own public estimate
-            my_est = jax.lax.dynamic_slice_in_dim(est, my, 1, axis=0)
-            payload = comp.compress(x - my_est)
-            gathered = jax.tree.map(
-                lambda a: jax.lax.all_gather(a, axis_name, axis=0, tiled=True),
-                payload,
-            )
-            # every replica advances the whole stack by the same payloads —
-            # including its own row, which must match what OTHER devices hold
-            # for this sender (never shortcut it with the true x)
-            est = est + comp.decompress(gathered, est)
-            my_est = jax.lax.dynamic_slice_in_dim(est, my, 1, axis=0)
-            d_i = None
-            if cfg.use_affinity_d:
-                # d on estimate differences (what receivers actually know of
-                # each other) — mirrors the vmap compressed path
-                nbr_avg = consensus_lib.mix_leaf(beta_row, est)
-                d_i = jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (x.ndim - 1)),
-                    (nbr_avg - my_est) / cfg.local_steps,
-                    jnp.zeros_like(x),
-                )
-            # convex mix: the receiver's own row is its true block (the self
-            # term is exact under any compressor); only this TEMPORARY view
-            # is patched — the carried estimate advances from payloads alone
-            xhat_full = est.at[my].set(x[0])
-            m_i = proto.mix_sharded_leaf(ctx, x, xhat_full)
-            if cfg.use_affinity_b:
-                m_i = m_i + cfg.eta_b * b_bias_leaves[i]
-            mixed_leaves.append(m_i)
-            d_leaves.append(d_i)
-            new_e.append(est)
-        leaves = mixed_leaves
-        e_leaves = new_e
-        if cfg.use_affinity_d:
-            d_bias = jax.tree.unflatten(treedef, d_leaves)
-
-    return state._replace(
-        params=jax.tree.unflatten(treedef, leaves),
-        d_bias=d_bias,
-        protocol=proto_state,
-        compression=jax.tree.unflatten(treedef, e_leaves),
-        round_idx=state.round_idx + 1,
-    )
-
-
-def _consensus_phase_sharded_async(
-    state: P2PState,
-    cfg: P2PConfig,
-    consts: protocols_lib.ProtocolConstants,
-    proto: protocols_lib.ConsensusProtocol,
-    *,
-    axis_name: str,
-    lanes,
-) -> P2PState:
-    """``consensus_phase_sharded`` under bounded-staleness delivery.
-
-    The same round semantics as the vmap ``_consensus_phase_async``, one peer
-    per mesh slice.  The cheap cross-peer exchange is one ``all_gather`` of
-    the K scalar snapshot AGES (the adaptive schedule's K-losses pattern):
-    every peer then computes the same (K,) delivery mask and the same
-    renormalized (K, K) decayed constants from the replicated round slice.
-    Published SNAPSHOT rows — not live parameters — ride the schedule's
-    static ppermute lanes; the delivery mask only gates which rows of the
-    buffer were refreshed before the sends, so the lane structure (and the
-    one-compile property) is untouched by who straggles when.
-
-    Because a round's published buffer is FIXED across its S consensus steps
-    (delivery is per round), each leaf is gathered once before the step loop
-    instead of per step — the async path trades the sync path's leaf
-    pipelining for S-fold fewer lane transfers.  The mix is the protocol's
-    ``mix_split_sharded_begin`` / ``mix_split_sharded_leaf`` pair: this
-    peer's row of the vmap path's diagonal/off-diagonal decomposition,
-    operation for operation (self term elementwise on the true block,
-    off-diagonal einsum row on the snapshot stack), which keeps the async
-    pod runtime fp32 BIT-IDENTICAL to the vmap ``_consensus_phase_async`` —
-    the same parity contract as the synchronous paths.  Push-sum's mass
-    lane rides inside ``mix_split_sharded_begin`` on the same decayed
-    matrix, so the renormalized column sums — and mass conservation — hold
-    exactly.
-    """
-    k = consts.w.shape[-1]
-    my = jax.lax.axis_index(axis_name)
-    st: StalenessState = state.staleness  # published (1, ...), age (1,)
-    age_full = jax.lax.all_gather(st.age, axis_name, axis=0, tiled=True)  # (K,)
-    delivered, age_full_new, decay = _staleness_delivery(
-        cfg, state.round_idx, age_full
-    )
-    del_mine = jax.lax.dynamic_slice(delivered, (my,), (1,))  # (1,) bool
-    published = jax.tree.map(
-        lambda p, q: jnp.where(
-            del_mine.reshape((-1,) + (1,) * (p.ndim - 1)), p, q
-        ),
-        state.params,
-        st.published,
-    )
-    age_mine = jax.lax.dynamic_slice(age_full_new, (my,), (1,))
-    a_consts = protocols_lib.age_decayed_constants(
-        consts, decay, proto.stochasticity
-    )
-    beta_row = jnp.take(a_consts.beta, my, axis=0)[None]  # (1, K), decayed
-    has_nbrs = jnp.sum(jnp.take(consts.beta, my, axis=0)[None], axis=1) > 0  # (1,)
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    b_bias_leaves = jax.tree.leaves(state.b_bias)
-    leaves, treedef = jax.tree.flatten(params)
-    pub_full_leaves = [
-        consensus_lib.gather_peer_leaf(pl, axis_name, lanes, k)
-        for pl in jax.tree.leaves(published)
-    ]
-    for _ in range(cfg.consensus_steps):
-        proto_state, ctx = proto.mix_split_sharded_begin(
-            proto_state, a_consts.w, axis_name=axis_name, lanes=lanes
-        )
-        mixed_leaves, d_leaves = [], []
-        for i, x in enumerate(leaves):
-            pub_full = pub_full_leaves[i]
-            d_i = None
-            if cfg.use_affinity_d:
-                # d from the snapshot stack as carried (own row = own
-                # published block) — mirrors the vmap async path, which
-                # mixes beta over the buffer itself
-                nbr_avg = consensus_lib.mix_leaf(beta_row, pub_full)
-                d_i = jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (x.ndim - 1)),
-                    (nbr_avg - x) / cfg.local_steps,
-                    jnp.zeros_like(x),
-                )
-            # convex split: self term on the true block (diagonal weight),
-            # off-diagonal accumulation on the snapshot stack — the own row
-            # of pub_full is never read
-            m_i = proto.mix_split_sharded_leaf(ctx, x, pub_full)
-            if cfg.use_affinity_b:
-                m_i = m_i + cfg.eta_b * b_bias_leaves[i]
-            mixed_leaves.append(m_i)
-            d_leaves.append(d_i)
-        leaves = mixed_leaves
-        if cfg.use_affinity_d:
-            d_bias = jax.tree.unflatten(treedef, d_leaves)
-
-    return state._replace(
-        params=jax.tree.unflatten(treedef, leaves),
-        d_bias=d_bias,
-        protocol=proto_state,
-        staleness=StalenessState(published=published, age=age_mine),
-        round_idx=state.round_idx + 1,
-    )
-
-
 MIX_MODES = ("auto", "bridge", "segment")
 _BRIDGE_MAX_PEERS = 64  # "auto" uses the bit-parity bridge mix up to here
-
-
-@telemetry.scoped("repro.consensus")
-def consensus_phase_hier(
-    state: P2PState,
-    cfg: P2PConfig,
-    *,
-    axis_name: str,
-    num_devices: int,
-    mix_mode: str,
-    ops: protocols_lib.SparseRoundOps | None = None,
-    dense_consts: protocols_lib.ProtocolConstants | None = None,
-) -> P2PState:
-    """``consensus_phase`` inside a shard_map block holding a (p, ...) BLOCK
-    of peers (p = K / devices > 1) — the hierarchical runtime's mix.
-
-    Two modes, selected by ``mix_mode``:
-
-    "bridge" (K <= 64): per leaf, all-gather the (K, ...) stack and run the
-    SAME full dense einsum the stacked runtime runs — ``dense_consts`` is the
-    round's (K, K) slice scattered back losslessly from the sparse schedule
-    (``graph.SparseSchedule.to_dense``) — then keep this device's p rows.
-    Slicing AFTER the reduction preserves every bit; (p, K)-row forms of the
-    matvec leaves (scalar parameters, the push-sum mass) reduce in a
-    different order and drift by an ulp.  Each device duplicates the full
-    K x K mix, which is exactly the regime's point: K <= 64 makes the
-    duplicated flops irrelevant next to fp32 bit-identity with the vmap and
-    pod runtimes.
-
-    "segment" (large K): per leaf, ring-stream the peer blocks across the
-    mesh and keep only this block's (p, D, ...) neighbor slots
-    (``consensus.ring_gather_slots``), then segment-sum with the sparse
-    ``ops`` (the round's degree-bounded ``SparseRoundOps``, replicated —
-    K*D floats, tiny next to parameters even at K = 4096).  Peak per-device
-    consensus memory is O(K * D * feat / devices) and traffic O(K * feat)
-    per device — no (K, K), no (K, feat) — at the cost of bitwise parity
-    (degree-bounded sums reduce in slot order; results are allclose to
-    dense, not bit-identical).
-    """
-    if cfg.consensus_steps == 0:
-        return state._replace(round_idx=state.round_idx + 1)
-
-    proto = protocols_lib.get_protocol(cfg.protocol)
-    p = jax.tree.leaves(state.params)[0].shape[0]
-    my = jax.lax.axis_index(axis_name)
-    row0 = (my * p).astype(jnp.int32)
-
-    if mix_mode == "bridge":
-        if dense_consts is None:
-            raise ValueError("bridge mode needs dense_consts (round (K, K) slice)")
-        beta_r = dense_consts.beta  # (K, K) f32
-        has_nbrs = jax.lax.dynamic_slice_in_dim(
-            jnp.sum(beta_r, axis=1) > 0, row0, p, axis=0
-        )  # (p,)
-        begin_kwargs = dict(dense_w=dense_consts.w, row0=row0, block_size=p)
-
-        def view(x):
-            return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-
-        def nbr_avg_fn(x_view):
-            full = consensus_lib.mix_leaf(beta_r, x_view)  # (K, ...)
-            return jax.lax.dynamic_slice_in_dim(full, row0, p, axis=0)
-
-    elif mix_mode == "segment":
-        if ops is None:
-            raise ValueError("segment mode needs ops (round SparseRoundOps)")
-        blk = protocols_lib.SparseRoundOps(
-            *(jax.lax.dynamic_slice_in_dim(o, row0, p, axis=0) for o in ops)
-        )
-        has_nbrs = jnp.sum(blk.beta, axis=1) > 0  # (p,)
-        begin_kwargs = dict(ops_block=blk)
-
-        def view(x):
-            return consensus_lib.ring_gather_slots(
-                x, blk.nbr_idx, axis_name, num_devices
-            )
-
-        def nbr_avg_fn(x_view):
-            return consensus_lib.slot_sum(blk.beta, x_view)
-
-    else:
-        raise ValueError(f"unknown mix_mode {mix_mode!r}; 'bridge' or 'segment'")
-
-    params, d_bias, proto_state = state.params, state.d_bias, state.protocol
-    b_bias_leaves = jax.tree.leaves(state.b_bias)
-    for _ in range(cfg.consensus_steps):
-        proto_state, ctx = proto.mix_hier_begin(
-            proto_state, mode=mix_mode, axis_name=axis_name,
-            num_devices=num_devices, **begin_kwargs,
-        )
-        leaves, treedef = jax.tree.flatten(params)
-        mixed_leaves, d_leaves = [], []
-        for i, x in enumerate(leaves):
-            x_view = view(x)
-            d_i = None
-            if cfg.use_affinity_d:
-                # d_k <- (1/T) sum_j beta_kj (w_j - w_k); isolated peers
-                # (all-zero beta row this round) keep d = 0
-                avg = nbr_avg_fn(x_view)
-                d_i = jnp.where(
-                    has_nbrs.reshape((-1,) + (1,) * (x.ndim - 1)),
-                    (avg - x) / cfg.local_steps,
-                    jnp.zeros_like(x),
-                )
-            m_i = proto.mix_hier_leaf(ctx, x, x_view)
-            if cfg.use_affinity_b:
-                m_i = m_i + cfg.eta_b * b_bias_leaves[i]
-            mixed_leaves.append(m_i)
-            d_leaves.append(d_i)
-        params = jax.tree.unflatten(treedef, mixed_leaves)
-        if cfg.use_affinity_d:
-            d_bias = jax.tree.unflatten(treedef, d_leaves)
-
-    return state._replace(
-        params=params, d_bias=d_bias, protocol=proto_state,
-        round_idx=state.round_idx + 1,
-    )
-
-
-def _make_hier_round_step(
-    loss_fn: LossFn,
-    cfg: P2PConfig,
-    data_sizes: np.ndarray | None = None,
-    *,
-    mesh,
-    axis_name: str,
-    peers_per_device: int,
-    mix_mode: str = "auto",
-):
-    """The hierarchical (vmap-within-device x shard_map) round step:
-    ``peers_per_device`` peers share each mesh slice, decoupling K from the
-    device count — K = 4096 runs on an 8-device mesh with 512 peers each.
-
-    The local phase is the SAME ``_local_phase_stats`` scan (vmap over the
-    (p, ...) block instead of the full (K, ...) stack — bit-identical rows),
-    and the consensus phase is ``consensus_phase_hier`` over the round's
-    degree-bounded ``graph.SparseSchedule`` operands.
-    """
-    from repro.sharding import specs as specs_lib
-
-    # adaptive / compression / async / real-model x hierarchical: all four
-    # rejections come from the declarative table, through the one formatter
-    features_lib.check_config(cfg, peers_per_device=peers_per_device)
-    loss_fn = resolve_loss_fn(loss_fn)
-    if mix_mode not in MIX_MODES:
-        raise ValueError(f"unknown mix_mode {mix_mode!r}; one of {MIX_MODES}")
-    num_devices, _ = specs_lib.hierarchical_layout(
-        cfg.num_peers, mesh, peer_axis=axis_name,
-        peers_per_device=peers_per_device,
-    )
-    mode = mix_mode
-    if mode == "auto":
-        mode = "bridge" if cfg.num_peers <= _BRIDGE_MAX_PEERS else "segment"
-
-    proto = protocols_lib.get_protocol(cfg.protocol)
-    sched = build_schedule(cfg)
-    if sched.directed and not proto.directed_capable:
-        warnings.warn(
-            f"protocol {cfg.protocol!r} on a directed schedule "
-            f"({sched.name!r}): a row-stochastic consensus point is biased on "
-            "asymmetric graphs — use protocol='push_sum' unless the bias is "
-            "deliberate",
-            stacklevel=2,
-        )
-    sparse = graph_lib.SparseSchedule.from_schedule(
-        sched, cfg.mixing, data_sizes=data_sizes,
-        consensus_step_size=cfg.consensus_step_size,
-        stochasticity=proto.stochasticity,
-    )
-    period = sparse.period
-    shard_map = _shard_map_fn()
-    from jax.sharding import PartitionSpec as P
-
-    if mode == "bridge":
-        # Lossless densification: the bridge mix replays the stacked
-        # runtime's full (K, K) einsums and slices this device's rows, so it
-        # wants the round constants in exactly the stacked runtime's form.
-        w_np, beta_np = sparse.to_dense()
-        w_s = jnp.asarray(w_np, jnp.float32)  # (R, K, K)
-        beta_s = jnp.asarray(beta_np, jnp.float32)
-
-        def block(state: P2PState, batches: PyTree, w, bt):
-            after_local, losses = local_phase(
-                state, loss_fn, batches, cfg, axis_name=axis_name
-            )
-            idx = jax.lax.rem(state.round_idx, jnp.int32(period))
-            after_cons = consensus_phase_hier(
-                after_local, cfg,
-                axis_name=axis_name, num_devices=num_devices, mix_mode=mode,
-                dense_consts=protocols_lib.ProtocolConstants(w=w[idx], beta=bt[idx]),
-            )
-            return after_local, after_cons, losses
-
-        extra_args = (w_s, beta_s)
-        extra_specs = (P(None, None, None), P(None, None, None))
-    else:
-        # stacked (R, ...) degree-bounded operands — R*K*D floats, replicated
-        self_w_s = jnp.asarray(sparse.self_w, jnp.float32)
-        nbr_idx_s = jnp.asarray(sparse.nbr_idx, jnp.int32)
-        nbr_w_s = jnp.asarray(sparse.nbr_w, jnp.float32)
-        beta_s = jnp.asarray(sparse.beta, jnp.float32)
-
-        def block(state: P2PState, batches: PyTree, sw, ni, nw, bt):
-            after_local, losses = local_phase(
-                state, loss_fn, batches, cfg, axis_name=axis_name
-            )
-            idx = jax.lax.rem(state.round_idx, jnp.int32(period))
-            after_cons = consensus_phase_hier(
-                after_local, cfg,
-                axis_name=axis_name, num_devices=num_devices, mix_mode=mode,
-                ops=protocols_lib.SparseRoundOps(sw[idx], ni[idx], nw[idx], bt[idx]),
-            )
-            return after_local, after_cons, losses
-
-        extra_args = (self_w_s, nbr_idx_s, nbr_w_s, beta_s)
-        extra_specs = (
-            P(None, None), P(None, None, None),
-            P(None, None, None), P(None, None, None),
-        )
-
-    def step(state: P2PState, batches: PyTree):
-        s_specs = specs_lib.peer_stacked_pspecs(state, peer_axis=axis_name)
-        b_specs = specs_lib.peer_batch_pspecs(batches, peer_axis=axis_name)
-        mapped = shard_map(
-            block,
-            mesh=mesh,
-            in_specs=(s_specs, b_specs) + extra_specs,
-            out_specs=(s_specs, s_specs, P(None)),
-        )
-        return mapped(state, batches, *extra_args)
-
-    return step
 
 
 def _make_round_step(
@@ -1468,37 +1026,59 @@ def _make_round_step(
     mix_mode: str = "auto",
 ):
     """The UNJITTED (state, batches) -> (after_local, after_consensus, losses)
-    round step shared by every driver.
+    round step shared by every driver and every runtime.
 
-    ``mesh=None`` builds the stacked/vmap step; a mesh builds the sharded
-    (``shard_map`` over ``axis_name``) step.  ``make_round_fn`` /
-    ``make_sharded_round_fn`` jit it per round; ``make_scan_driver`` scans a
-    whole chunk of calls inside one jitted program.  Sharing the step is what
-    keeps the python-loop and scan drivers running the SAME per-round
-    expression graph — the basis of their fp32 bit-parity contract.
+    One block: this round's constants -> local phase -> consensus phase.
+    ``mesh=None`` runs it on the stacked (vmap) runtime; a mesh wraps it in
+    one ``shard_map`` over ``axis_name``: one peer per slice (pod, the
+    ``_Lanes`` exchange), or ``peers_per_device > 1`` peers vmapped inside
+    each slice (hierarchical: ``_Bridge`` for K <= 64, else ``_Segment``;
+    ``mix_mode`` forces one), which decouples K from the device count.
+    ``make_round_fn`` / ``make_sharded_round_fn`` jit it per round;
+    ``make_scan_driver`` scans a whole chunk of calls inside one jitted
+    program.  Sharing the step is what keeps the python-loop and scan drivers
+    running the SAME per-round expression graph — the basis of their fp32
+    bit-parity contract.
 
-    ``cfg.schedule == "adaptive"`` swaps the pretraced ``round_idx % R``
-    constant stack for ``graph.adaptive_round_matrices``: the round's (K, K)
-    W/Beta are computed inside the step from ``state.adaptive`` (previous
-    round's per-peer losses + the threaded PRNG key), then the step stores
-    this round's per-peer mean losses and the advanced key for the next
-    round.  Still one compile per run — the selection is ordinary traced
-    arithmetic, not a host callback.
-
-    ``peers_per_device > 1`` (mesh required) builds the HIERARCHICAL step
-    instead (``_make_hier_round_step``): p = K / devices peers vmapped within
-    each mesh slice, sparse degree-bounded consensus across slices.
+    The only thing that varies is where the round's constants come from:
+    the pretraced (R, ...) stacks indexed by ``round_idx % R`` (dense
+    ``ProtocolConstants``; on the hierarchical runtime the sparse
+    ``SparseRoundOps``, or their lossless densification for the bridge), or,
+    for ``cfg.schedule == "adaptive"``, ``graph.adaptive_round_matrices`` of
+    ``state.adaptive`` (previous round's per-peer losses, all-gathered on
+    pod, + the threaded PRNG key); the step then stores this round's
+    per-peer mean losses and the advanced key.  Still one compile per run.
     """
-    if peers_per_device is not None and peers_per_device != 1:
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding import specs as specs_lib
+
+    loss_fn = resolve_loss_fn(loss_fn)
+    proto = protocols_lib.get_protocol(cfg.protocol)
+    hier = peers_per_device is not None and peers_per_device != 1
+    mode = None
+    if hier:
+        # adaptive / compression / async / real-model x hierarchical: all
+        # four rejections come from the declarative table
+        features_lib.check_config(cfg, peers_per_device=peers_per_device)
         if mesh is None:
             raise ValueError("peers_per_device > 1 needs a mesh (hierarchical runtime)")
-        return _make_hier_round_step(
-            loss_fn, cfg, data_sizes, mesh=mesh, axis_name=axis_name,
-            peers_per_device=peers_per_device, mix_mode=mix_mode,
+        if mix_mode not in MIX_MODES:
+            raise ValueError(f"unknown mix_mode {mix_mode!r}; one of {MIX_MODES}")
+        num_devices, _ = specs_lib.hierarchical_layout(
+            cfg.num_peers, mesh, peer_axis=axis_name,
+            peers_per_device=peers_per_device,
         )
-    loss_fn = resolve_loss_fn(loss_fn)
+        mode = mix_mode
+        if mode == "auto":
+            mode = "bridge" if cfg.num_peers <= _BRIDGE_MAX_PEERS else "segment"
+    elif mesh is not None and dict(mesh.shape).get(axis_name) != cfg.num_peers:
+        raise ValueError(
+            f"mesh axis {axis_name!r} must have exactly num_peers="
+            f"{cfg.num_peers} slices, got mesh shape {dict(mesh.shape)} "
+            "(see repro.launch.mesh.make_peer_mesh)"
+        )
     adaptive = cfg.schedule == "adaptive"
-    proto = protocols_lib.get_protocol(cfg.protocol)
     sizes_dev = (
         None if data_sizes is None
         else jnp.asarray(np.asarray(data_sizes), jnp.float32)
@@ -1511,159 +1091,101 @@ def _make_round_step(
         steps_np, _ = compute_profile(cfg)
         steps_dev = jnp.asarray(steps_np)  # (K,) int32
 
-    def adaptive_consts(ad: "AdaptiveState", losses_full: jax.Array):
-        """(this round's ProtocolConstants, next round's key) from run state.
-
-        ``losses_full`` is the gathered (K,) selection signal — identical
-        bits in both runtimes (the vmap runtime reads the stacked leaf, the
-        pod runtime all-gathers the K scalars), so the matching, and with it
-        the round's whole topology, is too.
-        """
-        key_round, key_next = jax.random.split(ad.key[0])
-        w, beta = graph_lib.adaptive_round_matrices(
-            losses_full, key_round, rule=cfg.partner_rule,
-            eps=cfg.adaptive_eps, data_sizes=sizes_dev,
+    stacks: tuple = ()  # the pretraced (R, ...) round constants
+    if hier:
+        sparse = graph_lib.SparseSchedule.from_schedule(
+            _checked_schedule(cfg), cfg.mixing, data_sizes=data_sizes,
             consensus_step_size=cfg.consensus_step_size,
             stochasticity=proto.stochasticity,
         )
-        return protocols_lib.ProtocolConstants(w=w, beta=beta), key_next
+        if mode == "bridge":
+            stacks = tuple(jnp.asarray(m, jnp.float32) for m in sparse.to_dense())
+        else:  # degree-bounded operands — R*K*D floats, replicated
+            stacks = (
+                jnp.asarray(sparse.self_w, jnp.float32),
+                jnp.asarray(sparse.nbr_idx, jnp.int32),
+                jnp.asarray(sparse.nbr_w, jnp.float32),
+                jnp.asarray(sparse.beta, jnp.float32),
+            )
+    elif not adaptive:
+        consts_np, sched = protocol_constants(cfg, data_sizes)
+        stacks = (
+            jnp.asarray(consts_np.w, jnp.float32),  # (R, K, K)
+            jnp.asarray(consts_np.beta, jnp.float32),
+        )
+    # the stacked or pod exchange; the hierarchical ones hold the round's
+    # traced rows, so the block builds them
+    exchange = _Exchange()
+    if mesh is not None and not hier:
+        # Any pair may be matched on an adaptive round, so its candidate lane
+        # set covers the COMPLETE graph: the ppermute structure stays a
+        # trace-time constant while the round's on-device weights null every
+        # edge the matching did not select (zero rows meet zero weights).
+        lanes = (
+            graph_lib.edge_color_lanes(~np.eye(cfg.num_peers, dtype=bool))
+            if adaptive else graph_lib.schedule_lanes(sched)
+        )
+        exchange = _Lanes(axis_name, lanes, cfg.num_peers)
+
+    def block(state: P2PState, batches: PyTree, *stacks):
+        ex = exchange
+        if adaptive:
+            ad = state.adaptive
+            key_round, key_next = jax.random.split(ad.key[0])
+            # the selection signal is the same (K,) bits on every runtime, so
+            # the matching, and with it the round's whole topology, is too
+            w, beta = graph_lib.adaptive_round_matrices(
+                ex.everyone(ad.last_losses), key_round, rule=cfg.partner_rule,
+                eps=cfg.adaptive_eps, data_sizes=sizes_dev,
+                consensus_step_size=cfg.consensus_step_size,
+                stochasticity=proto.stochasticity,
+            )
+            consts = protocols_lib.ProtocolConstants(w=w, beta=beta)
+        else:
+            idx = jax.lax.rem(state.round_idx, jnp.int32(stacks[0].shape[0]))
+            picked = [s[idx] for s in stacks]
+            if hier:
+                row0 = (jax.lax.axis_index(axis_name) * peers_per_device).astype(jnp.int32)
+                ex = _Bridge(axis_name, row0, peers_per_device)
+            if mode == "segment":  # this device's rows of the sparse operands
+                consts = protocols_lib.SparseRoundOps(*(ex.own(o) for o in picked))
+                ex = _Segment(axis_name, num_devices, consts.nbr_idx)
+            else:
+                consts = protocols_lib.ProtocolConstants(*picked)
+        steps_k = None if steps_dev is None else ex.own(steps_dev)
+        # the per-step losses all-gather inside the local phase on a mesh, so
+        # they are replicated — and reduced over the same (K,) vector as on
+        # the vmap runtime
+        after_local, losses_tk = _local_phase_stats(
+            state, loss_fn, batches, cfg,
+            axis_name=None if mesh is None else axis_name, steps_k=steps_k,
+        )
+        losses = jnp.mean(losses_tk, axis=1)  # (T,) per-step mean over peers
+        if adaptive:
+            after_local = after_local._replace(adaptive=AdaptiveState(
+                key=jnp.broadcast_to(key_next[None, :], ad.key.shape),
+                last_losses=ex.own(jnp.mean(losses_tk, axis=0)),  # per peer
+            ))
+        if hier:
+            after_cons = consensus_phase_hier(after_local, cfg, consts, exchange=ex)
+        else:
+            after_cons = consensus_phase(after_local, cfg, consts, exchange=ex)
+        return after_local, after_cons, losses
 
     if mesh is None:
-        if adaptive:
-
-            def step(state: P2PState, batches: PyTree):
-                ad = state.adaptive
-                consts, key_next = adaptive_consts(ad, ad.last_losses)
-                after_local, losses_tk = _local_phase_stats(
-                    state, loss_fn, batches, cfg, steps_k=steps_dev
-                )
-                new_ad = AdaptiveState(
-                    key=jnp.broadcast_to(key_next[None, :], ad.key.shape),
-                    last_losses=jnp.mean(losses_tk, axis=0),  # (K,) per peer
-                )
-                after_local = after_local._replace(adaptive=new_ad)
-                after_cons = consensus_phase(after_local, cfg, consts)
-                return after_local, after_cons, jnp.mean(losses_tk, axis=1)
-
-            return step
-
-        consts_np, _ = protocol_constants(cfg, data_sizes)
-        consts = protocols_lib.ProtocolConstants(
-            w=jnp.asarray(consts_np.w, jnp.float32),  # (R, K, K)
-            beta=jnp.asarray(consts_np.beta, jnp.float32),
-        )
-        period = consts.w.shape[0]
-
-        def step(state: P2PState, batches: PyTree):
-            idx = jax.lax.rem(state.round_idx, jnp.int32(period))
-            return run_round(
-                state, loss_fn, batches, cfg,
-                protocols_lib.round_constants(consts, idx),
-                steps_k=steps_dev,
-            )
-
-        return step
-
-    from repro.sharding import specs as specs_lib
-
-    axis_sizes = dict(mesh.shape)
-    if axis_sizes.get(axis_name) != cfg.num_peers:
-        raise ValueError(
-            f"mesh axis {axis_name!r} must have exactly num_peers="
-            f"{cfg.num_peers} slices, got mesh shape {axis_sizes} "
-            "(see repro.launch.mesh.make_peer_mesh)"
-        )
+        return lambda state, batches: block(state, batches, *stacks)
     shard_map = _shard_map_fn()
-    from jax.sharding import PartitionSpec as P
-
-    def my_steps_block():
-        # this peer's (1,) slice of the replicated (K,) step budgets (None
-        # for the uniform profile — the unmasked legacy scan)
-        if steps_dev is None:
-            return None
-        my = jax.lax.axis_index(axis_name)
-        return jax.lax.dynamic_slice(steps_dev, (my,), (1,))
-
-    if adaptive:
-        # Any pair may be matched on any round, so the candidate lane set
-        # covers the COMPLETE graph: the ppermute structure (lanes and their
-        # perms) stays a trace-time constant while the round's on-device
-        # weights null every edge the matching did not select — zero rows of
-        # the gathered params meet zero mixing weights, contributing exactly
-        # +-0.0, just as on a pretraced schedule's absent edges.
-        union = ~np.eye(cfg.num_peers, dtype=bool)
-        lanes = graph_lib.edge_color_lanes(union)
-
-        def block_adaptive(state: P2PState, batches: PyTree):
-            after_local, losses_tk = _local_phase_stats(
-                state, loss_fn, batches, cfg, axis_name=axis_name,
-                steps_k=my_steps_block(),
-            )
-            ad = state.adaptive
-            # the cheap K-vector exchange: each peer contributes one scalar
-            losses_full = jax.lax.all_gather(
-                ad.last_losses, axis_name, axis=0, tiled=True
-            )  # (K,)
-            consts, key_next = adaptive_consts(ad, losses_full)
-            my = jax.lax.axis_index(axis_name)
-            peer_losses = jnp.mean(losses_tk, axis=0)  # (K,) replicated
-            new_ad = AdaptiveState(
-                key=key_next[None, :],  # this peer's (1, 2) block
-                last_losses=jax.lax.dynamic_slice(peer_losses, (my,), (1,)),
-            )
-            after_local = after_local._replace(adaptive=new_ad)
-            after_cons = consensus_phase_sharded(
-                after_local, cfg, consts, axis_name=axis_name, lanes=lanes
-            )
-            return after_local, after_cons, jnp.mean(losses_tk, axis=1)
-
-        def step(state: P2PState, batches: PyTree):
-            s_specs = specs_lib.peer_stacked_pspecs(state, peer_axis=axis_name)
-            b_specs = specs_lib.peer_batch_pspecs(batches, peer_axis=axis_name)
-            mapped = shard_map(
-                block_adaptive,
-                mesh=mesh,
-                in_specs=(s_specs, b_specs),
-                out_specs=(s_specs, s_specs, P(None)),
-            )
-            return mapped(state, batches)
-
-        return step
-
-    consts_np, sched = protocol_constants(cfg, data_sizes)
-    w_dev = jnp.asarray(consts_np.w, jnp.float32)  # (R, K, K)
-    beta_dev = jnp.asarray(consts_np.beta, jnp.float32)
-    period = w_dev.shape[0]
-    lanes = graph_lib.schedule_lanes(sched)
-
-    def block(state: P2PState, batches: PyTree, w_stack, beta_stack):
-        # the per-step loss means all-gather inside the block (axis_name), so
-        # the (T,) output is replicated — and reduced over the same (K,)
-        # vector as the vmap runtime
-        after_local, losses = local_phase(
-            state, loss_fn, batches, cfg, axis_name=axis_name,
-            steps_k=my_steps_block(),
-        )
-        idx = jax.lax.rem(state.round_idx, jnp.int32(period))
-        consts = protocols_lib.round_constants(
-            protocols_lib.ProtocolConstants(w=w_stack, beta=beta_stack), idx
-        )
-        after_cons = consensus_phase_sharded(
-            after_local, cfg, consts, axis_name=axis_name, lanes=lanes
-        )
-        return after_local, after_cons, losses
 
     def step(state: P2PState, batches: PyTree):
         s_specs = specs_lib.peer_stacked_pspecs(state, peer_axis=axis_name)
         b_specs = specs_lib.peer_batch_pspecs(batches, peer_axis=axis_name)
-        c_spec = P(None, None, None)
         mapped = shard_map(
             block,
             mesh=mesh,
-            in_specs=(s_specs, b_specs, c_spec, c_spec),
+            in_specs=(s_specs, b_specs) + tuple(P(*(None,) * s.ndim) for s in stacks),
             out_specs=(s_specs, s_specs, P(None)),
         )
-        return mapped(state, batches, w_dev, beta_dev)
+        return mapped(state, batches, *stacks)
 
     return step
 

@@ -5,14 +5,17 @@ choice into one instance of a ``ConsensusProtocol`` so the same runtime
 (``repro.core.p2p``) can also run directed, Sparse-Push-style schedules where
 a peer sends without receiving.  A protocol owns three things:
 
-    init_state(params, data_sizes)  -> per-run protocol state (leading K axis)
-    constants(schedule, mixing, ..) -> stacked (R, K, K) numpy round constants
-    mix(proto_state, params, consts)-> one consensus step on the stacked params
+    init_state(params, data_sizes)   -> per-run protocol state (leading K axis)
+    constants(schedule, mixing, ..)  -> stacked (R, K, K) numpy round constants
+    begin(proto_state, consts, ex)   -> per consensus step: scalar work and
+                                        this runtime's weight operands, then
+    mix_leaf / mix_split_leaf(ctx, x, view) per parameter leaf
 
 ``constants`` runs once on the host at setup; the jitted round function
-closes over the stack and feeds ``mix`` one round's (K, K) slice selected by
-``round_idx % R`` *inside* the traced program, preserving the
-one-compile-per-run property for every protocol.
+closes over the stack and feeds ``begin`` one round's (K, K) slice selected
+by ``round_idx % R`` *inside* the traced program, preserving the
+one-compile-per-run property for every protocol.  ``mix`` is the whole-tree
+stacked step built from ``begin`` and ``mix_leaf``.
 
 State layout per protocol (the ``P2PState.protocol`` leaf):
 
@@ -71,7 +74,7 @@ def age_decayed_constants(
 ) -> ProtocolConstants:
     """One async round's renormalized age-decayed mixing constants.
 
-    Bounded-staleness consensus (``p2p._consensus_phase_async``) mixes each
+    Bounded-staleness consensus (``p2p._snapshot_round``) mixes each
     SENDER j's last published snapshot with its weight scaled by ``decay[j]``
     (``staleness_decay ** age_j`` in (0, 1]; 1.0 = fresh).  Scaling alone
     would break stochasticity, so the freed mass is absorbed by the
@@ -138,8 +141,47 @@ class SparseRoundOps(NamedTuple):
     beta: jax.Array  # (K, D) f32 — affinity weights
 
 
+class StackedExchange:
+    """How a runtime sees its neighbours, as far as a protocol needs to know:
+    the stacked (vmap) runtime's exchange, where every block holds all K
+    peers and each method is the identity.  The mesh runtimes' exchanges
+    (``p2p._Lanes``, ``p2p._Bridge``, ``p2p._Segment``) override them:
+
+      ``rows(a)`` — the rows of a (K, ...) array this runtime's mix computes:
+                    all K (vmap; the hierarchical bridge, which slices after
+                    the reduction), this peer's one row (pod), or the block
+                    as given (segment);
+      ``own(a)``  — the rows of a (K, ...) array this runtime holds;
+      ``view(v)`` — what this runtime sees of its senders' block ``v``: the
+                    stack itself (vmap), the lanes' reconstruction (pod), the
+                    all-gathered stack (bridge) or the (p, D) neighbour slots
+                    (segment).
+    """
+
+    def rows(self, a: jax.Array) -> jax.Array:
+        """The rows this runtime's mix computes."""
+        return a
+
+    def own(self, a: jax.Array) -> jax.Array:
+        """The rows this runtime holds."""
+        return a
+
+    def view(self, v: jax.Array) -> jax.Array:
+        """This runtime's view of its senders' block."""
+        return v
+
+
 class ConsensusProtocol:
-    """Interface of one consensus-step rule over stacked (K, ...) parameters."""
+    """Interface of one consensus-step rule over stacked (K, ...) parameters.
+
+    Every runtime drives a protocol through one per-step ``begin`` and one
+    per-leaf mix (``mix_leaf``, or ``mix_split_leaf`` on substituting
+    wires); what varies between runtimes is the exchange ``ex`` the phase
+    passes in (``StackedExchange`` and its subclasses).  The protocol's
+    scalar state (push-sum's mass) rides ``ex.view`` like the parameters,
+    but always uncompressed and undelayed: the phase's wire stages
+    substitute parameter views only.
+    """
 
     name: str = "base"
     # Whether the protocol's consensus point is unbiased on directed
@@ -169,192 +211,64 @@ class ConsensusProtocol:
         """Stacked (R, K, K) numpy round constants for a whole schedule."""
         raise NotImplementedError
 
+    def begin(
+        self, proto_state: PyTree, consts, ex, *, split: bool = False
+    ) -> tuple[PyTree, Any]:
+        """Per-consensus-step setup, run once per step before the leaves.
+
+        Everything that does not scale with the parameter leaves lives here:
+        the protocol's scalar work (push-sum's mass lane and new mass) and
+        this runtime's weight operands.  ``consts`` is the round's
+        ``ProtocolConstants`` (one (K, K) slice) or, on the hierarchical
+        segment exchange, this device's ``SparseRoundOps`` block.  ``split``
+        asks for the operands of ``mix_split_leaf`` instead of ``mix_leaf``.
+        Returns ``(new proto_state, ctx)``; ``ctx`` is opaque to the runtime.
+        """
+        raise NotImplementedError
+
+    def mix_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """One leaf of the plain mix: every sender, own row included, is
+        read from ``view`` (``x`` is this runtime's own block, used only by
+        the segment form's self term).  Returns ``ex.rows`` rows of output:
+        the runtime keeps its own with ``ex.own``-style slicing after the
+        reduction, which is what keeps every dense form fp32 bit-identical
+        to the stacked einsum."""
+        raise NotImplementedError
+
+    def mix_split_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """One leaf of the CONVEX split mix, for wires whose senders are
+        seen through a substitute (compressed estimates, stale snapshots).
+
+        The self term — never on the wire — runs on this runtime's TRUE
+        block ``x`` (the diagonal weights), the off-diagonal accumulation on
+        the substitute stack ``view``, whose own rows are never read.  This
+        is a contraction of ``x`` toward values the substitutes bound, so
+        estimate lag or staleness cannot feed back into parameter growth;
+        CHOCO's additive correction form ``x + (mix(x_hat) - x_hat_self)``
+        was tried and diverges exponentially on the non-IID k8 workload at
+        1% top-k (the own-estimate error enters with a POSITIVE sign and
+        compounds through local training).
+        """
+        raise NotImplementedError
+
     def mix(
         self, proto_state: PyTree, params: PyTree, consts: ProtocolConstants
     ) -> tuple[PyTree, PyTree]:
-        """One consensus step; returns (new proto_state, new params)."""
-        raise NotImplementedError
+        """One plain step on the whole stacked tree (the vmap runtime's
+        mix); returns (new proto_state, new params)."""
+        proto_state, ctx = self.begin(proto_state, consts, StackedExchange())
+        return proto_state, jax.tree.map(lambda x: self.mix_leaf(ctx, x, x), params)
 
-    def mix_compressed(
-        self,
-        proto_state: PyTree,
-        params: PyTree,
-        params_hat: PyTree,
-        consts: ProtocolConstants,
-    ) -> tuple[PyTree, PyTree]:
-        """``mix`` when receivers only see COMPRESSED neighbor payloads.
 
-        ``params`` is the true stacked tree; ``params_hat`` the shared
-        public-estimate stack every node reconstructs from the wire payloads
-        (``repro.compression``, WARM-STARTED at the initial parameters).
-        Implementations mix the CONVEX form: the self term — never on the
-        wire — uses the TRUE parameters (diagonal weights x ``params``), the
-        off-diagonal accumulation runs on the dense estimates.  This is a
-        contraction of ``x`` toward values the estimates bound, so estimate
-        lag cannot feed back into parameter growth; CHOCO's additive
-        correction form ``x + (mix(x_hat) - x_hat_self)`` was tried here and
-        diverges exponentially on the non-IID k8 workload at 1% top-k (the
-        own-estimate error enters with a POSITIVE sign and compounds through
-        local training).  Any protocol state (push-sum mass) rides
-        UNCOMPRESSED — only parameter leaves are estimated.  Returns
-        (new proto_state, new params).
-        """
-        raise NotImplementedError(
-            f"protocol {self.name!r} does not implement the compressed mix"
-        )
+def _diag_split(a: jax.Array, ex) -> tuple[jax.Array, jax.Array]:
+    """(off-diagonal rows, own diagonal entries) of a (K, K) f32 matrix."""
+    diag = jnp.diagonal(a)  # (K,)
+    return ex.rows(a - jnp.diag(diag)), ex.own(diag)
 
-    def mix_sharded_begin(
-        self,
-        proto_state: PyTree,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PyTree, Any]:
-        """Per-consensus-step setup of the sharded mix, run ONCE per step.
 
-        Everything that does not scale with the parameter leaves lives here:
-        selecting this peer's weight row, and (for push_sum) ppermuting the
-        scalar mass lane and computing the new mass.  Returns
-        ``(new_proto_state, ctx)``; ``ctx`` is an opaque value consumed by
-        ``mix_sharded_leaf`` for every parameter leaf of the step.  Splitting
-        the step this way lets the runtime pipeline leaves — issue leaf
-        ``i+1``'s ppermutes while leaf ``i``'s matvec is still running —
-        without touching per-leaf arithmetic (the bit-parity contract).
-
-        Protocols that predate this split (whole-tree ``mix_sharded``
-        override only) need not implement it: the sharded runtime detects
-        the base-class method and falls back to the unpipelined path.
-        """
-        raise NotImplementedError(
-            f"protocol {self.name!r} implements neither mix_sharded_begin/"
-            "mix_sharded_leaf (pipelined) nor a mix_sharded override (legacy)"
-        )
-
-    def mix_sharded_leaf(self, ctx, x_block: jax.Array, x_full: jax.Array) -> jax.Array:
-        """One leaf of the sharded mix: this peer's row of ``mix``'s einsum.
-
-        ``x_block`` is this peer's (1, ...) slice, ``x_full`` the (K, ...)
-        reconstruction from ``consensus.gather_peer_leaf`` (zero rows for
-        non-in-neighbors).  Must compute exactly the arithmetic of ``mix``
-        restricted to this peer's row — the runtime's parity contract is fp32
-        bit-identity with the vmap path.
-        """
-        raise NotImplementedError
-
-    def mix_split_sharded_begin(
-        self,
-        proto_state: PyTree,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PyTree, Any]:
-        """Per-consensus-step setup of the sharded CONVEX-SPLIT mix.
-
-        The sharded counterpart of ``mix_compressed``'s diagonal/off-diagonal
-        split, used by bounded-staleness consensus
-        (``p2p._consensus_phase_sharded_async``): the self term runs on this
-        peer's TRUE (1, ...) block, the off-diagonal accumulation on a
-        substitute (K, ...) stack (stale snapshots there; estimates would
-        work the same way).  Implementations must mirror ``mix_compressed``
-        operation for operation — this peer's row of the same off-diagonal
-        einsum, the same elementwise self term, the same add order — so the
-        pod async runtime stays fp32 bit-identical to the vmap async runtime
-        (the ``mix``/``mix_sharded_leaf`` parity contract, restated for the
-        split form).  Returns (new proto_state, ctx for
-        ``mix_split_sharded_leaf``).
-        """
-        raise NotImplementedError(
-            f"protocol {self.name!r} does not implement the sharded split mix"
-        )
-
-    def mix_split_sharded_leaf(
-        self, ctx, x_block: jax.Array, sub_full: jax.Array
-    ) -> jax.Array:
-        """One leaf of the sharded convex-split mix.
-
-        ``x_block`` is this peer's true (1, ...) slice; ``sub_full`` the
-        (K, ...) substitute stack gathered over the schedule's lanes (zero
-        rows for non-in-neighbors — they meet zero off-diagonal weights, so
-        they contribute exactly +-0.0 like the dense form's absent edges).
-        The own row of ``sub_full`` is never read: its weight lives on the
-        diagonal, which multiplies ``x_block``.
-        """
-        raise NotImplementedError
-
-    def mix_hier_begin(
-        self,
-        proto_state: PyTree,
-        *,
-        mode: str,
-        axis_name: str,
-        num_devices: int,
-        dense_w: jax.Array | None = None,
-        row0: jax.Array | None = None,
-        block_size: int | None = None,
-        ops_block: "SparseRoundOps | None" = None,
-    ) -> tuple[PyTree, Any]:
-        """Per-consensus-step setup of the HIERARCHICAL mix (vmap-within-
-        device x shard_map), run once per step.
-
-        ``mode`` selects the operand form and the neighbor-view convention
-        that ``mix_hier_leaf`` will receive:
-
-          "bridge"  — ``dense_w`` is the round's full (K, K) matrix
-                      (losslessly densified from the sparse schedule),
-                      ``row0``/``block_size`` this device's row window.
-                      x_view is the all-gathered (K, ...) stack; the mix
-                      replays the stacked runtime's FULL dense einsum and
-                      slices this device's rows after the reduction — fp32
-                      bit-identical to the stacked runtime (the K <= 64
-                      lossless-conversion regime).
-          "segment" — ``ops_block`` is this device's (K/devices)-row slice
-                      of the round's ``SparseRoundOps``.  x_view is the
-                      ring-gathered (p, D, ...) neighbor slots
-                      (``consensus.ring_gather_slots``); the mix is a
-                      weighted segment sum, O(K * D * feat / devices) memory
-                      with no (K, K) or (K, feat) intermediate — the large-K
-                      path (allclose to dense, not bitwise).
-        """
-        raise NotImplementedError(
-            f"protocol {self.name!r} does not implement the hierarchical "
-            "(peers_per_device > 1) mix"
-        )
-
-    def mix_hier_leaf(self, ctx, x_block: jax.Array, x_view: jax.Array) -> jax.Array:
-        """One leaf of the hierarchical mix: this device's (p, ...) block of
-        ``mix``'s output, from the block itself plus the mode's neighbor view
-        (see ``mix_hier_begin``)."""
-        raise NotImplementedError
-
-    def mix_sharded(
-        self,
-        proto_state: PyTree,
-        params: PyTree,
-        params_full: PyTree,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PyTree, PyTree]:
-        """``mix`` inside a shard_map block of the sharded peer-axis runtime.
-
-        ``params``/``proto_state`` leaves carry this peer's (1, ...) block of
-        the stacked axis; ``params_full`` is the (K, ...) reconstruction from
-        ``consensus.gather_peer_rows`` (zero rows for non-in-neighbors) and
-        ``w_mat`` the round's full (K, K) protocol matrix (replicated — it is
-        tiny next to the parameters).  Implemented via ``mix_sharded_begin`` +
-        ``mix_sharded_leaf`` so the whole-tree and leaf-pipelined paths share
-        one definition of the arithmetic.
-        """
-        proto_state, ctx = self.mix_sharded_begin(
-            proto_state, w_mat, axis_name=axis_name, lanes=lanes
-        )
-        mixed = jax.tree.map(
-            lambda b, f: self.mix_sharded_leaf(ctx, b, f), params, params_full
-        )
-        return proto_state, mixed
+def _bcast(v: jax.Array, like: jax.Array) -> jax.Array:
+    """``v`` (one entry per leading row) reshaped to broadcast over ``like``."""
+    return v.reshape(v.shape + (1,) * (like.ndim - v.ndim))
 
 
 class GossipProtocol(ConsensusProtocol):
@@ -381,119 +295,38 @@ class GossipProtocol(ConsensusProtocol):
         )
         return ProtocolConstants(w=w, beta=beta)
 
-    def mix(
-        self, proto_state: PyTree, params: PyTree, consts: ProtocolConstants
-    ) -> tuple[PyTree, PyTree]:
-        """One stacked mix step: ``W x`` per leaf (Eq. 4's averaging)."""
-        return proto_state, consensus_lib.mix_stacked(consts.w, params)
+    def begin(
+        self, proto_state: PyTree, consts, ex, *, split: bool = False
+    ) -> tuple[PyTree, Any]:
+        """This runtime's rows of W (split: off-diagonal rows + own
+        diagonal), or the segment block's slot weights as they are."""
+        if isinstance(consts, SparseRoundOps):
+            return proto_state, consts
+        if split:
+            return proto_state, _diag_split(consts.w.astype(jnp.float32), ex)
+        return proto_state, ex.rows(consts.w)
 
-    def mix_compressed(
-        self,
-        proto_state: PyTree,
-        params: PyTree,
-        params_hat: PyTree,
-        consts: ProtocolConstants,
-    ) -> tuple[PyTree, PyTree]:
-        """Convex estimate-gossip: ``W_kk x_k + sum_{j != k} W_kj x_hat_j``.
+    def mix_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """``W x`` (Eq. 4's averaging): the rows' einsum over the view, or
+        the weighted slot sum of the segment form."""
+        if isinstance(ctx, SparseRoundOps):
+            return consensus_lib.mix_slots(ctx.self_w, ctx.nbr_w, x, view)
+        return consensus_lib.mix_leaf(ctx, view)
+
+    def mix_split_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """Convex gossip: ``W_kk x_k + sum_{j != k} W_kj view_j``.
 
         Row-stochastic W makes this a convex combination of the true own
-        parameters and the (warm-started, payload-advanced) neighbor
-        estimates — exactly ``W x`` once the estimates converge, and
-        unconditionally bounded by them before that.
+        parameters and the substitutes — exactly ``W x`` once the
+        substitutes equal the live parameters.
         """
-        w = consts.w.astype(jnp.float32)
-        diag = jnp.diagonal(w)  # (K,)
-        w_off = w - jnp.diag(diag)
-
-        def leaf(x, xh):
-            feat = (1,) * (x.ndim - 1)
-            own = diag.reshape((-1,) + feat) * x.astype(jnp.float32)
-            nbr = jnp.einsum(
-                "kj,j...->k...",
-                w_off,
-                xh.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            return (own + nbr).astype(x.dtype)
-
-        return proto_state, jax.tree.map(leaf, params, params_hat)
-
-    def mix_sharded_begin(
-        self,
-        proto_state: PyTree,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PyTree, Any]:
-        """Per-round pod setup: this peer's (1, K) row of the mixing matrix."""
-        my = jax.lax.axis_index(axis_name)
-        w_row = jnp.take(w_mat, my, axis=0)[None]
-        return proto_state, w_row
-
-    def mix_sharded_leaf(self, ctx, x_block: jax.Array, x_full: jax.Array) -> jax.Array:
-        """This peer's (1, K) x (K, ...) row of the stacked path's einsum."""
-        return consensus_lib.mix_leaf(ctx, x_full)
-
-    def mix_split_sharded_begin(
-        self,
-        proto_state: PyTree,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PyTree, Any]:
-        """Pod setup for the convex split mix: (off-diag row, own diagonal)."""
-        my = jax.lax.axis_index(axis_name)
-        w = w_mat.astype(jnp.float32)
-        diag = jnp.diagonal(w)  # (K,)
-        w_off = w - jnp.diag(diag)
-        off_row = jnp.take(w_off, my, axis=0)[None]  # (1, K)
-        diag_mine = jnp.take(diag, my)[None]  # (1,)
-        return proto_state, (off_row, diag_mine)
-
-    def mix_split_sharded_leaf(
-        self, ctx, x_block: jax.Array, sub_full: jax.Array
-    ) -> jax.Array:
-        """``mix_compressed``'s leaf, operation for operation, on this row.
-
-        ``own = diag * x_block`` (elementwise) plus the off-diagonal einsum's
-        row over the substitute stack — bitwise the stacked path's row.
-        """
-        off_row, diag_mine = ctx
-        feat = (1,) * (x_block.ndim - 1)
-        own = diag_mine.reshape((-1,) + feat) * x_block.astype(jnp.float32)
-        nbr = consensus_lib.mix_leaf(off_row, sub_full)
-        return (own + nbr).astype(x_block.dtype)
-
-    def mix_hier_begin(
-        self,
-        proto_state: PyTree,
-        *,
-        mode: str,
-        axis_name: str,
-        num_devices: int,
-        dense_w: jax.Array | None = None,
-        row0: jax.Array | None = None,
-        block_size: int | None = None,
-        ops_block: "SparseRoundOps | None" = None,
-    ) -> tuple[PyTree, Any]:
-        """Hierarchical-runtime setup: bridge (dense W) or segment weights."""
-        if mode == "bridge":
-            return proto_state, ("bridge", (dense_w, row0, block_size))
-        return proto_state, ("segment", (ops_block.self_w, ops_block.nbr_w))
-
-    def mix_hier_leaf(self, ctx, x_block: jax.Array, x_view: jax.Array) -> jax.Array:
-        """Hierarchical mix per leaf: full-einsum-then-slice or slot sum."""
-        tag, payload = ctx
-        if tag == "bridge":
-            # the stacked runtime's FULL (K, K) x (K, ...) einsum, then this
-            # device's rows — slicing after the reduction keeps the bits
-            w_mat, row0, p = payload
-            full = consensus_lib.mix_leaf(w_mat, x_view)
-            return jax.lax.dynamic_slice_in_dim(full, row0, p, axis=0)
-        self_w, nbr_w = payload
-        return consensus_lib.mix_slots(self_w, nbr_w, x_block, x_view)
+        w_off, diag = ctx
+        own = _bcast(diag, x) * x.astype(jnp.float32)
+        nbr = jnp.einsum(
+            "kj,j...->k...", w_off, view.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        return (own + nbr).astype(x.dtype)
 
 
 class PushSumProtocol(ConsensusProtocol):
@@ -534,222 +367,68 @@ class PushSumProtocol(ConsensusProtocol):
         )
         return ProtocolConstants(w=w, beta=beta)
 
-    def mix(
-        self, proto_state: PushSumState, params: PyTree, consts: ProtocolConstants
-    ) -> tuple[PushSumState, PyTree]:
-        """One push-sum step: mass-biased averaging de-biased by ``y_new``."""
-        a = consts.w.astype(jnp.float32)
-        y = proto_state.mass.astype(jnp.float32)  # (K,)
-        y_new = jnp.einsum("kj,j->k", a, y, precision=jax.lax.Precision.HIGHEST)
+    def begin(
+        self, proto_state: PushSumState, consts, ex, *, split: bool = False
+    ) -> tuple[PushSumState, Any]:
+        """The mass lane: the senders' masses ride ``ex.view`` (the same
+        lanes, gather or ring as the parameters), then the new mass.
 
-        def leaf(x):
+        The dense forms run the FULL (K, K) x (K,) matvec and keep this
+        runtime's rows: a (1, K) x (K,) dot is too narrow for XLA to reduce
+        in the same order as the stacked matvec, while the full product —
+        on zero-padded masses whose foreign rows are discarded — shares its
+        primitive shape and therefore its bits.  The segment form pre-scales
+        the slot weights by the sender masses (the mass-lane trick of
+        kernels/consensus_mix/ops.py, block-sharded).
+        """
+        if isinstance(consts, SparseRoundOps):
+            y = proto_state.mass.astype(jnp.float32)  # (p,)
+            y_slots = ex.view(y)  # (p, D)
+            ops = consts._replace(
+                self_w=consts.self_w * y, nbr_w=consts.nbr_w * y_slots
+            )
+            y_new = ops.self_w + jnp.sum(ops.nbr_w, axis=1)
+            return PushSumState(mass=y_new), (ops, y_slots, y_new)
+        a = consts.w.astype(jnp.float32)  # (K, K)
+        y = proto_state.mass.astype(jnp.float32)  # this runtime's masses
+        y_send = ex.view(y)  # (K,) — zero for peers this one never hears
+        y_new = jnp.einsum("kj,j->k", a, y_send, precision=jax.lax.Precision.HIGHEST)
+        state = PushSumState(mass=ex.own(y_new))
+        if split:
+            return state, _diag_split(a, ex) + (y, y_send, ex.rows(y_new))
+        return state, (ex.rows(a), y_send, ex.rows(y_new))
+
+    def mix_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """One push-sum step: mass-biased numerators, de-biased by the new
+        mass (``num_k = sum_j A_kj y_j w_j``, ``w_k' = num_k / y_k'``).
+
+        Zero view rows (non-in-neighbours) stay zero after the bias multiply
+        and meet zero weights — exactly +-0.0, as in the dense einsum.
+        """
+        w, y_send, y_new = ctx
+        if isinstance(w, SparseRoundOps):
             xf = x.astype(jnp.float32)
-            biased = xf * y.reshape((-1,) + (1,) * (x.ndim - 1))
+            num = _bcast(w.self_w, xf) * xf + jnp.sum(
+                _bcast(w.nbr_w, view) * view.astype(jnp.float32), axis=1
+            )
+        else:
+            biased = view.astype(jnp.float32) * _bcast(y_send, view)
             num = jnp.einsum(
-                "kj,j...->k...", a, biased, precision=jax.lax.Precision.HIGHEST
+                "kj,j...->k...", w, biased, precision=jax.lax.Precision.HIGHEST
             )
-            out = num / y_new.reshape((-1,) + (1,) * (x.ndim - 1))
-            return out.astype(x.dtype)
+        return (num / _bcast(y_new, num)).astype(x.dtype)
 
-        return PushSumState(mass=y_new), jax.tree.map(leaf, params)
-
-    def mix_compressed(
-        self,
-        proto_state: PushSumState,
-        params: PyTree,
-        params_hat: PyTree,
-        consts: ProtocolConstants,
-    ) -> tuple[PushSumState, PyTree]:
-        """Convex estimate-push-sum: the numerator's self term uses the true
-        biased parameters, the off-diagonal terms the (warm-started) biased
-        estimates; the (K,) mass and the resulting y' ride UNCOMPRESSED
-        (mass conservation sum y == K stays exact)."""
-        a = consts.w.astype(jnp.float32)
-        diag = jnp.diagonal(a)  # (K,)
-        a_off = a - jnp.diag(diag)
-        y = proto_state.mass.astype(jnp.float32)  # (K,)
-        y_new = jnp.einsum("kj,j->k", a, y, precision=jax.lax.Precision.HIGHEST)
-
-        def leaf(x, xh):
-            feat = (1,) * (x.ndim - 1)
-            yf = y.reshape((-1,) + feat)
-            own = diag.reshape((-1,) + feat) * (x.astype(jnp.float32) * yf)
-            nbr = jnp.einsum(
-                "kj,j...->k...",
-                a_off,
-                xh.astype(jnp.float32) * yf,
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            out = (own + nbr) / y_new.reshape((-1,) + feat)
-            return out.astype(x.dtype)
-
-        return PushSumState(mass=y_new), jax.tree.map(leaf, params, params_hat)
-
-    def mix_sharded_begin(
-        self,
-        proto_state: PushSumState,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PushSumState, Any]:
-        """Row-restricted ``mix``, scalar part: the (K,) mass rides the same
-        ppermute lanes as the parameters, once per consensus step.
-
-        The scalar mass update runs the FULL (K, K) x (K,) matvec and keeps
-        one row: a (1, K) x (K,) dot is too narrow for XLA to reduce in the
-        same order as the stacked matvec, while the full product — on
-        zero-padded masses whose foreign rows are discarded — shares its
-        primitive shape and therefore its bits.
-        """
-        k = w_mat.shape[-1]
-        my = jax.lax.axis_index(axis_name)
-        a = w_mat.astype(jnp.float32)  # (K, K)
-        a_row = jnp.take(a, my, axis=0)[None]  # (1, K)
-        y = proto_state.mass.astype(jnp.float32)  # (1,)
-        y_full = consensus_lib.gather_peer_rows(y, axis_name, lanes, k)  # (K,)
-        y_new_all = jnp.einsum("kj,j->k", a, y_full, precision=jax.lax.Precision.HIGHEST)
-        y_new = jnp.take(y_new_all, my)[None]  # (1,) — only our row is meaningful
-        return PushSumState(mass=y_new), (a_row, y_full, y_new)
-
-    def mix_sharded_leaf(self, ctx, x_block: jax.Array, x_full: jax.Array) -> jax.Array:
-        """Row-restricted ``mix``, one parameter leaf.
-
-        Mirrors ``mix`` operation for operation (f32 bias multiply, HIGHEST-
-        precision einsums, divide, cast back) so the sharded runtime stays
-        bit-identical to the stacked one.
-        """
-        a_row, y_full, y_new = ctx
-        xf = x_full.astype(jnp.float32)
-        # zero rows (non-in-neighbors) stay zero after the bias multiply,
-        # and meet zero weights in a_row — contributing exactly +-0.0,
-        # as in the dense einsum where the zero lives in A instead.
-        biased = xf * y_full.reshape((-1,) + (1,) * (x_full.ndim - 1))
-        num = jnp.einsum(
-            "kj,j...->k...", a_row, biased, precision=jax.lax.Precision.HIGHEST
-        )
-        out = num / y_new.reshape((-1,) + (1,) * (x_full.ndim - 1))
-        return out.astype(x_block.dtype)
-
-    def mix_split_sharded_begin(
-        self,
-        proto_state: PushSumState,
-        w_mat: jax.Array,
-        *,
-        axis_name: str,
-        lanes,
-    ) -> tuple[PushSumState, Any]:
-        """Sharded split mix, scalar part: ``mix_compressed``'s mass update.
-
-        The (K,) mass rides the schedule's lanes and the FULL (K, K) x (K,)
-        matvec keeps one row — exactly ``mix_sharded_begin`` (the mass is
-        never substituted) — plus this peer's slice of the numerator's
-        diagonal/off-diagonal decomposition.
-        """
-        k = w_mat.shape[-1]
-        my = jax.lax.axis_index(axis_name)
-        a = w_mat.astype(jnp.float32)  # (K, K)
-        diag = jnp.diagonal(a)  # (K,)
-        a_off = a - jnp.diag(diag)
-        off_row = jnp.take(a_off, my, axis=0)[None]  # (1, K)
-        diag_mine = jnp.take(diag, my)[None]  # (1,)
-        y = proto_state.mass.astype(jnp.float32)  # (1,)
-        y_full = consensus_lib.gather_peer_rows(y, axis_name, lanes, k)  # (K,)
-        y_new_all = jnp.einsum("kj,j->k", a, y_full, precision=jax.lax.Precision.HIGHEST)
-        y_new = jnp.take(y_new_all, my)[None]  # (1,)
-        return PushSumState(mass=y_new), (off_row, diag_mine, y, y_full, y_new)
-
-    def mix_split_sharded_leaf(
-        self, ctx, x_block: jax.Array, sub_full: jax.Array
-    ) -> jax.Array:
-        """Sharded split mix, one leaf: ``mix_compressed``'s numerator row.
-
-        Self term on the true biased block, off-diagonal einsum row on the
-        sender-mass-biased substitute stack, divided by the row's new mass —
-        operation for operation the vmap expression, for fp32 bit-parity.
-        """
-        off_row, diag_mine, y, y_full, y_new = ctx
-        feat = (1,) * (x_block.ndim - 1)
-        own = diag_mine.reshape((-1,) + feat) * (
-            x_block.astype(jnp.float32) * y.reshape((-1,) + feat)
-        )
-        biased = sub_full.astype(jnp.float32) * y_full.reshape((-1,) + feat)
+    def mix_split_leaf(self, ctx, x: jax.Array, view: jax.Array) -> jax.Array:
+        """Convex push-sum: the numerator's self term on the true biased
+        block, the off-diagonal terms on the sender-mass-biased substitutes,
+        divided by the new mass (mass conservation stays exact)."""
+        a_off, diag, y, y_send, y_new = ctx
+        own = _bcast(diag, x) * (x.astype(jnp.float32) * _bcast(y, x))
         nbr = jnp.einsum(
-            "kj,j...->k...", off_row, biased, precision=jax.lax.Precision.HIGHEST
+            "kj,j...->k...", a_off, view.astype(jnp.float32) * _bcast(y_send, view),
+            precision=jax.lax.Precision.HIGHEST,
         )
-        out = (own + nbr) / y_new.reshape((-1,) + feat)
-        return out.astype(x_block.dtype)
-
-    def mix_hier_begin(
-        self,
-        proto_state: PushSumState,
-        *,
-        mode: str,
-        axis_name: str,
-        num_devices: int,
-        dense_w: jax.Array | None = None,
-        row0: jax.Array | None = None,
-        block_size: int | None = None,
-        ops_block: "SparseRoundOps | None" = None,
-    ) -> tuple[PushSumState, Any]:
-        """Hierarchical setup: advance the mass lane (bridge or segment)."""
-        y = proto_state.mass.astype(jnp.float32)  # (p,) this device's masses
-        if mode == "bridge":
-            # Replay ``mix``'s FULL (K, K) x (K,) mass matvec on the gathered
-            # masses and keep this device's rows — same reason the pod
-            # runtime does (see ``mix_sharded_begin``): any narrower dot
-            # reduces in a different order than the stacked matvec.  Bridge
-            # mode is the K <= 64 parity regime, where the full (K, K) A is
-            # exactly the dense path's footprint.
-            a = dense_w.astype(jnp.float32)  # (K, K)
-            y_full = jax.lax.all_gather(y, axis_name, axis=0, tiled=True)  # (K,)
-            y_new_all = jnp.einsum(
-                "kj,j->k", a, y_full, precision=jax.lax.Precision.HIGHEST
-            )
-            y_new = jax.lax.dynamic_slice_in_dim(
-                y_new_all, row0, block_size, axis=0
-            )
-            return (
-                PushSumState(mass=y_new),
-                ("bridge", (a, y_full, y_new_all, row0, block_size)),
-            )
-        # segment: the (p, D) sender masses ride the same ring as the
-        # parameter slots; weights pre-scaled by the sender's mass turn the
-        # leaf mix into the push-sum numerator sum (the mass-lane trick of
-        # kernels/consensus_mix/ops.py, block-sharded)
-        y_slots = consensus_lib.ring_gather_slots(
-            y, ops_block.nbr_idx, axis_name, num_devices
-        )  # (p, D)
-        self_w_y = ops_block.self_w * y
-        nbr_w_y = ops_block.nbr_w * y_slots
-        y_new = self_w_y + jnp.sum(nbr_w_y, axis=1)
-        return PushSumState(mass=y_new), ("segment", (self_w_y, nbr_w_y, y_new))
-
-    def mix_hier_leaf(self, ctx, x_block: jax.Array, x_view: jax.Array) -> jax.Array:
-        """Hierarchical push-sum leaf: numerator mix divided by new mass."""
-        tag, payload = ctx
-        feat = (1,) * (x_block.ndim - 1)
-        if tag == "bridge":
-            # ``mix``'s full-K expression, operation for operation, then this
-            # device's rows (the divide is elementwise — slicing after it is
-            # exact)
-            a, y_full, y_new_all, row0, p = payload
-            xf = x_view.astype(jnp.float32)
-            biased = xf * y_full.reshape((-1,) + feat)
-            num = jnp.einsum(
-                "kj,j...->k...", a, biased, precision=jax.lax.Precision.HIGHEST
-            )
-            out = num / y_new_all.reshape((-1,) + feat)
-            return jax.lax.dynamic_slice_in_dim(out, row0, p, axis=0).astype(
-                x_block.dtype
-            )
-        self_w_y, nbr_w_y, y_new = payload
-        xf = x_block.astype(jnp.float32)
-        slots = x_view.astype(jnp.float32)  # (p, D, ...)
-        num = self_w_y.reshape((-1,) + feat) * xf + jnp.sum(
-            nbr_w_y.reshape(nbr_w_y.shape + feat) * slots, axis=1
-        )
-        return (num / y_new.reshape((-1,) + feat)).astype(x_block.dtype)
+        return ((own + nbr) / _bcast(y_new, own)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ so no advanced neighbor copy ever exists — the weighted accumulation runs
 directly on the compressed representation (the in-register int8 -> f32 cast
 is free next to the memory saved).  ``x_hat_self`` is the peer's OWN public
 estimate: the affinity d of the compressed runtime operates on estimate
-differences (see ``p2p._consensus_phase_compressed``), while the mix's self
+differences (see the CHOCO wire of ``p2p.consensus_phase``), while the mix's self
 term stays exact on the true ``x``.  The no-neighbor guard cannot read the
 folded beta (scale = 0 would corrupt it), so the RAW beta sum rides in as a
 separate flag.

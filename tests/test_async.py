@@ -255,8 +255,7 @@ def test_sync_config_takes_synchronous_code_path(monkeypatch):
     def boom(*a, **k):  # pragma: no cover - must never run
         raise AssertionError("async machinery entered on the sync path")
 
-    monkeypatch.setattr(p2p, "_consensus_phase_async", boom)
-    monkeypatch.setattr(p2p, "_consensus_phase_sharded_async", boom)
+    monkeypatch.setattr(p2p, "_snapshot_round", boom)
     monkeypatch.setattr(p2p, "_staleness_delivery", boom)
     monkeypatch.setattr(p2p, "compute_profile", boom)
     monkeypatch.setattr(protocols_lib, "age_decayed_constants", boom)
@@ -443,7 +442,7 @@ def test_async_one_compile():
 def test_hier_runtime_rejects_async():
     cfg = _cfg(num_peers=8)
     with pytest.raises(ValueError, match="asynchronous.*not supported"):
-        p2p._make_hier_round_step(
+        p2p._make_round_step(
             _mlp_loss, cfg, mesh=None, axis_name="pod", peers_per_device=2
         )
 
@@ -452,7 +451,7 @@ def test_hier_runtime_rejects_steps_profile_alone():
     cfg = _cfg(num_peers=8, staleness_bound=0)
     assert cfg.use_async
     with pytest.raises(ValueError, match="asynchronous.*not supported"):
-        p2p._make_hier_round_step(
+        p2p._make_round_step(
             _mlp_loss, cfg, mesh=None, axis_name="pod", peers_per_device=2
         )
 
@@ -532,7 +531,7 @@ def test_pod_sync_config_takes_synchronous_code_path(monkeypatch):
     def boom(*a, **k):  # pragma: no cover - must never run
         raise AssertionError("async machinery entered on the sync pod path")
 
-    monkeypatch.setattr(p2p, "_consensus_phase_sharded_async", boom)
+    monkeypatch.setattr(p2p, "_snapshot_round", boom)
     monkeypatch.setattr(p2p, "_staleness_delivery", boom)
     monkeypatch.setattr(protocols_lib, "age_decayed_constants", boom)
     cfg = _cfg(steps_profile="uniform", staleness_bound=0, num_peers=K8)

@@ -211,10 +211,10 @@ def test_ef_first_payload_is_zero_after_warm_start(key):
     params = jax.vmap(_init_fn)(jax.random.split(key, K))
     comp = compression_lib.TopKCompressor(0.1)
     est = comp.init_estimate(params)
-    payloads, est2 = compression_lib.ef_compress_tree(comp, params, est)
-    for p in payloads:
-        assert np.asarray(p.values).max() == 0.0
-    _assert_trees_equal(est, est2, "estimate after zero payload")
+    for x, e in zip(jax.tree.leaves(params), jax.tree.leaves(est)):
+        payload, e2 = compression_lib.ef_compress_leaf(comp, x, e)
+        assert np.asarray(payload.values).max() == 0.0
+        _assert_trees_equal(e, e2, "estimate after zero payload")
 
 
 # ---------------------------------------------------------------------------
@@ -232,10 +232,7 @@ def test_none_takes_uncompressed_code_path(monkeypatch):
 
     monkeypatch.setattr(compression_lib.NoneCompressor, "compress", boom)
     monkeypatch.setattr(compression_lib.NoneCompressor, "decompress", boom)
-    monkeypatch.setattr(compression_lib, "ef_compress_tree", boom)
-    monkeypatch.setattr(
-        compression_lib.compressors, "ef_compress_tree", boom, raising=False
-    )
+    monkeypatch.setattr(p2p, "_choco_advance", boom)
     cfg = _cfg()
     state = p2p.init_state(jax.random.PRNGKey(0), _init_fn, cfg)
     assert state.compression == ()
@@ -366,7 +363,7 @@ def test_compressed_one_compile():
 def test_hier_runtime_rejects_compression():
     cfg = _cfg(compressor="topk", num_peers=8)
     with pytest.raises(ValueError, match="compressor.*not supported"):
-        p2p._make_hier_round_step(
+        p2p._make_round_step(
             _mlp_loss, cfg, mesh=None, axis_name="pod", peers_per_device=2
         )
 
@@ -377,7 +374,7 @@ def test_hier_runtime_rejects_adaptive():
         schedule="adaptive",
     )
     with pytest.raises(ValueError, match="adaptive.*not supported"):
-        p2p._make_hier_round_step(
+        p2p._make_round_step(
             _mlp_loss, cfg, mesh=None, axis_name="pod", peers_per_device=2
         )
 

@@ -1,4 +1,4 @@
-"""Consensus operators: stacked einsum vs sparse gather vs mesh collectives."""
+"""Consensus operators: stacked einsum vs sparse gather vs lane gather."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,111 +39,6 @@ def test_mix_sparse_equals_dense(rng, topo):
         np.testing.assert_allclose(sparse[key], dense[key], atol=1e-5)
 
 
-def test_mix_psum_under_vmap_axis(rng):
-    """Complete-graph psum form == dense mixing (peer axis via vmap axis_name)."""
-    k = 4
-    g = gl.build_graph("complete", k)
-    w = gl.mixing_matrix(g, "uniform_neighbor")
-    self_w, peer_w = w[0, 0], w[0, 1]
-    tree = _tree(rng, k)
-
-    def per_peer(x):
-        return cl.mix_psum(x, "peer", self_weight=self_w, peer_weight=peer_w)
-
-    out = jax.vmap(per_peer, axis_name="peer")(tree)
-    want = cl.mix_stacked(jnp.asarray(w, jnp.float32), tree)
-    for key in tree:
-        np.testing.assert_allclose(out[key], want[key], atol=1e-5)
-
-
-def test_mix_ring_under_vmap_axis(rng):
-    k = 5
-    g = gl.build_graph("ring", k)
-    w = gl.mixing_matrix(g, "uniform_neighbor")
-    tree = _tree(rng, k)
-
-    def per_peer(x):
-        return cl.mix_ring(
-            x, "peer",
-            self_weight=w[0, 0], left_weight=w[0, k - 1], right_weight=w[0, 1],
-        )
-
-    out = jax.vmap(per_peer, axis_name="peer")(tree)
-    want = cl.mix_stacked(jnp.asarray(w, jnp.float32), tree)
-    for key in tree:
-        np.testing.assert_allclose(out[key], want[key], atol=1e-5)
-
-
-@pytest.mark.mesh
-@pytest.mark.skipif(
-    jax.device_count() < 4,
-    reason="needs >= 4 devices (XLA_FLAGS=--xla_force_host_platform_device_count=8)",
-)
-def test_mix_psum_under_real_mesh(rng):
-    """Complete-graph psum == dense mixing with the peer axis on a REAL mesh
-    (shard_map), not a vmap-faked axis_name."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core.p2p import _shard_map_fn
-    from repro.launch.mesh import make_peer_mesh
-
-    k = 4
-    g = gl.build_graph("complete", k)
-    w = gl.mixing_matrix(g, "uniform_neighbor")
-    tree = _tree(rng, k)
-    mesh = make_peer_mesh(k)
-    shard_map = _shard_map_fn()
-
-    fn = jax.jit(
-        shard_map(
-            lambda x: cl.mix_psum(x, "pod", self_weight=w[0, 0], peer_weight=w[0, 1]),
-            mesh=mesh,
-            in_specs=({"w": P("pod", None, None), "b": P("pod", None)},),
-            out_specs={"w": P("pod", None, None), "b": P("pod", None)},
-        )
-    )
-    out = fn(tree)
-    want = cl.mix_stacked(jnp.asarray(w, jnp.float32), tree)
-    for key in tree:
-        np.testing.assert_allclose(np.asarray(out[key]), np.asarray(want[key]), atol=1e-5)
-
-
-@pytest.mark.mesh
-@pytest.mark.skipif(
-    jax.device_count() < 4,
-    reason="needs >= 4 devices (XLA_FLAGS=--xla_force_host_platform_device_count=8)",
-)
-def test_mix_ring_under_real_mesh(rng):
-    """Ring gossip's two collective-permutes == dense mixing on a real mesh."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.core.p2p import _shard_map_fn
-    from repro.launch.mesh import make_peer_mesh
-
-    k = 4
-    g = gl.build_graph("ring", k)
-    w = gl.mixing_matrix(g, "uniform_neighbor")
-    tree = _tree(rng, k)
-    mesh = make_peer_mesh(k)
-    shard_map = _shard_map_fn()
-
-    fn = jax.jit(
-        shard_map(
-            lambda x: cl.mix_ring(
-                x, "pod",
-                self_weight=w[0, 0], left_weight=w[0, k - 1], right_weight=w[0, 1],
-            ),
-            mesh=mesh,
-            in_specs=({"w": P("pod", None, None), "b": P("pod", None)},),
-            out_specs={"w": P("pod", None, None), "b": P("pod", None)},
-        )
-    )
-    out = fn(tree)
-    want = cl.mix_stacked(jnp.asarray(w, jnp.float32), tree)
-    for key in tree:
-        np.testing.assert_allclose(np.asarray(out[key]), np.asarray(want[key]), atol=1e-5)
-
-
 @pytest.mark.mesh
 @pytest.mark.skipif(
     jax.device_count() < 4,
@@ -167,7 +62,7 @@ def test_gather_peer_rows_under_real_mesh(rng, topo):
 
     fn = jax.jit(
         shard_map(
-            lambda v: cl.gather_peer_rows(v, "pod", lanes, k)[None],
+            lambda v: cl.gather_peer_leaf(v, "pod", lanes, k)[None],
             mesh=mesh,
             in_specs=(P("pod", None),),
             out_specs=P("pod", None, None),

@@ -119,7 +119,7 @@ def test_hierarchical_pairs_reject_with_peers_per_device(kwargs, match):
 def test_real_model_rejected_by_hier_round_step_builder():
     cfg = p2p.P2PConfig(num_peers=8, model="rwkv6_seqmnist")
     with pytest.raises(ValueError, match="rwkv6_seqmnist.*hierarchical"):
-        p2p._make_hier_round_step(
+        p2p._make_round_step(
             lambda p, b: 0.0, cfg, mesh=object(), axis_name="pod",
             peers_per_device=2,
         )

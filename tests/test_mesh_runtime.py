@@ -382,95 +382,24 @@ def test_train_cli_fails_fast_on_missing_devices(capsys):
     assert "num_peers=2" in err
 
 
-class _LegacyGossip(protocols.ConsensusProtocol):
-    """A protocol written against the PRE-scan sharded interface: whole-tree
-    ``mix_sharded`` override, no ``mix_sharded_begin``/``mix_sharded_leaf``."""
-
-    name = "legacy_gossip_test"
-
-    def init_state(self, params, data_sizes=None):
-        return ()
-
-    def mix(self, proto_state, params, consts):
-        return proto_state, cl.mix_stacked(consts.w, params)
-
-    def mix_sharded(self, proto_state, params, params_full, w_mat, *, axis_name, lanes):
-        my = jax.lax.axis_index(axis_name)
-        w_row = jnp.take(w_mat, my, axis=0)[None]
-        return proto_state, cl.mix_stacked(w_row, params_full)
-
-
-def test_legacy_protocol_mix_sharded_fallback(rng):
-    """consensus_phase_sharded must route a begin/leaf-less protocol through
-    its whole-tree mix_sharded override (unpipelined fallback) instead of
-    hitting the base class's NotImplementedError or ignoring the override."""
-    if _LegacyGossip.name not in protocols.protocol_names():
-        protocols.register_protocol(_LegacyGossip())
-    k = 4
-    cfg = p2p.P2PConfig(
-        algorithm="p2pl_affinity", num_peers=k, local_steps=2,
-        consensus_steps=1, eta_d=0.5, topology="ring",
-        protocol=_LegacyGossip.name,
-    )
+def test_gossip_mix_sharded_under_vmap_axis(rng):
+    """The protocol's per-leaf mix on the pod exchange is exercisable without
+    a mesh: a vmap axis stands in for the pod axis (lane gather + row einsum
+    == dense mix)."""
+    k = 6
     g = gl.build_graph("ring", k)
     sched = gl.static_schedule(g)
     w, beta = gl.schedule_matrices(sched, "metropolis")
     lanes = gl.schedule_lanes(sched)
-    consts = protocols.ProtocolConstants(
-        jnp.asarray(w[0], jnp.float32), jnp.asarray(beta[0], jnp.float32)
-    )
-    params = {"w": jnp.asarray(rng.normal(size=(k, 5)), jnp.float32)}
-    zeros = jax.tree.map(jnp.zeros_like, params)
-    state = p2p.P2PState(
-        params=params, momentum=zeros, d_bias=zeros, b_bias=zeros,
-        round_idx=jnp.zeros((), jnp.int32), protocol=(),
-    )
-
-    blocked = p2p.P2PState(
-        params=jax.tree.map(lambda x: x[:, None], params),
-        momentum=jax.tree.map(lambda x: x[:, None], zeros),
-        d_bias=jax.tree.map(lambda x: x[:, None], zeros),
-        b_bias=jax.tree.map(lambda x: x[:, None], zeros),
-        round_idx=state.round_idx, protocol=(),
-    )
-    axes = p2p.P2PState(
-        params=0, momentum=0, d_bias=0, b_bias=0, round_idx=None, protocol=None
-    )
-
-    def per_peer(block):
-        out = p2p.consensus_phase_sharded(
-            block, cfg, consts, axis_name="peer", lanes=lanes
-        )
-        return jax.tree.map(lambda x: x[0], (out.params, out.d_bias))
-
-    got_params, got_d = jax.vmap(per_peer, in_axes=(axes,), axis_name="peer")(blocked)
-    want = p2p.consensus_phase(state, cfg, consts)
-    np.testing.assert_allclose(
-        np.asarray(got_params["w"]), np.asarray(want.params["w"]), atol=1e-6
-    )
-    np.testing.assert_allclose(
-        np.asarray(got_d["w"]), np.asarray(want.d_bias["w"]), atol=1e-6
-    )
-
-
-def test_gossip_mix_sharded_under_vmap_axis(rng):
-    """The protocol's sharded mix rule is exercisable without a mesh: a vmap
-    axis stands in for the pod axis (lane gather + row einsum == dense mix)."""
-    k = 6
-    g = gl.build_graph("ring", k)
-    sched = gl.static_schedule(g)
-    w, _ = gl.schedule_matrices(sched, "metropolis")
-    lanes = gl.schedule_lanes(sched)
     w_dev = jnp.asarray(w[0], jnp.float32)
+    consts = protocols.ProtocolConstants(w_dev, jnp.asarray(beta[0], jnp.float32))
     tree = {"w": jnp.asarray(rng.normal(size=(k, 5)), jnp.float32)}
     proto = protocols.get_protocol("gossip")
+    ex = p2p._Lanes("peer", lanes, k)
 
     def per_peer(block):
-        full = cl.gather_peer_rows(block, "peer", lanes, k)
-        _, mixed = proto.mix_sharded(
-            (), block, full, w_dev, axis_name="peer", lanes=lanes
-        )
-        return jax.tree.map(lambda x: x[0], mixed)
+        _, ctx = proto.begin((), consts, ex)
+        return jax.tree.map(lambda x: proto.mix_leaf(ctx, x, ex.view(x))[0], block)
 
     blocks = jax.tree.map(lambda x: x[:, None], tree)  # (K, 1, ...) blocks
     out = jax.vmap(per_peer, axis_name="peer")(blocks)
